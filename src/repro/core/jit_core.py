@@ -9,7 +9,7 @@ Two layers share this module:
    `lax.scan` kernels (`tent_choose_wave_padded_jnp`,
    `tent_on_complete_many_jnp`). Arrays are padded to power-of-two buckets
    so one compiled kernel serves every wave/drain of a scenario, and all
-   kernels run under `jax.experimental.enable_x64`, so results are
+   kernels run under `x64()` (`jax.enable_x64(True)`), so results are
    bit-identical to the numpy path (pinned in tests/test_jit_parity.py).
    The scalar/wave Python path stays in charge of everything stateful —
    staged hops, retries, substitutions, app callbacks — exactly as before;
@@ -55,6 +55,7 @@ __all__ = [
     "simulate_spray_ref",
     "spray_single",
     "spray_sweep",
+    "x64",
     "JIT_MIN",
     "JIT_MIN_FLOOR",
     "JIT_MIN_CEIL",
@@ -69,10 +70,13 @@ def jax_available() -> bool:
     return True
 
 
-def _x64():
-    from jax.experimental import enable_x64
+def x64():
+    """Context manager under which every kernel of this module runs: JAX
+    builds float64/int64 arrays inside it, so the jitted paths compute in the
+    same precision as their numpy twins. Shared with the parity tests."""
+    import jax
 
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def _bucket(n: int, floor: int = 8) -> int:
@@ -178,7 +182,7 @@ class EngineJitCore:
         valid = np.zeros(ps, dtype=bool)
         valid[:n_s] = True
         kern = _engine_kernels()["choose"]
-        with _x64():
+        with x64():
             c_j, qa_j, qo_j, rr_j = kern(
                 q, gl, gr, bw, b0, b1, pen, ex, ln, valid,
                 policy._rr, policy.gamma)
@@ -213,7 +217,7 @@ class EngineJitCore:
         to = np.zeros(pm, dtype=np.float64)
         to[:m] = t_obs
         kern = _engine_kernels()["drain"]
-        with _x64():
+        with x64():
             b0o, b1o, qo, ewo, co = kern(*state, sl, ln, qa, to)
             out = tuple(np.asarray(a) for a in (b0o, b1o, qo, ewo, co))
         store.scatter_complete_state(*out)
@@ -271,6 +275,17 @@ def _seed_key(base_seed: int, seed_index: int):
     return jax.random.fold_in(jax.random.PRNGKey(base_seed), seed_index)
 
 
+def _opaque(x):
+    """`x` as a value XLA cannot see through. Its simplifier rewrites a
+    division by a constant into a multiply by the (inexact) reciprocal,
+    which the numpy twin, dividing, cannot reproduce; every constant
+    divisor of the fused sim goes through here."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.optimization_barrier(jnp.asarray(x, dtype=float))
+
+
 def _draws_jnp(p: SprayProgram, key):
     """Raw per-seed randomness, all drawn up front so the jax sim and the
     numpy ref consume identical bits: window-jitter uniforms in [-1, 1]
@@ -292,7 +307,7 @@ def _draws_jnp(p: SprayProgram, key):
     # rounding), which the eagerly-materialized `make_draws` copy and the
     # numpy twin cannot reproduce. A division result feeding the add is
     # contraction-proof, so eager and jitted draws stay bit-identical.
-    inv_sigma = math.inf if p.jitter == 0 else 1.0 / float(p.jitter)
+    inv_sigma = _opaque(math.inf if p.jitter == 0 else 1.0 / float(p.jitter))
     jm = 1.0 + jnp.abs(
         jax.random.normal(kj, (p.rounds, p.wave, 2))) / inv_sigma
     return uf, us, ud, jm
@@ -302,7 +317,7 @@ def make_draws(p: SprayProgram, *, base_seed: int = 0,
                seed_index: int = 0) -> Dict[str, np.ndarray]:
     """Materialized numpy copy of one seed's raw draws — the common input
     feeding both `simulate_spray_ref` and the jax path in parity tests."""
-    with _x64():
+    with x64():
         uf, us, ud, jm = _draws_jnp(p, _seed_key(base_seed, seed_index))
         return {"uf": np.asarray(uf), "us": np.asarray(us),
                 "ud": np.asarray(ud), "jm": np.asarray(jm)}
@@ -520,7 +535,7 @@ def _build_sim(p: SprayProgram, policy: str, fault_jitter: float):
 
     def simulate(key):
         # All program constants materialize at trace time, inside the
-        # caller's enable_x64 scope — hoisting them to build time would
+        # caller's x64() scope — hoisting them to build time would
         # commit them as float32 and silently demote the whole sim.
         FS = jnp.asarray(p.fail_start, dtype=float)
         FE = jnp.asarray(p.fail_end, dtype=float)
@@ -541,6 +556,8 @@ def _build_sim(p: SprayProgram, policy: str, fault_jitter: float):
         b0a = ext(p.beta0_alpha, 0.0)
         b0_init = ext(p.beta0, 0.0)
         b1_init = ext(p.beta1, 1.0)
+        bw_score, bw_src, bw_dst = (
+            _opaque(bw_score), _opaque(bw_src), _opaque(bw_dst))
         arange = jnp.arange(D + 1)
 
         def _select(scores, rr_, gamma_):
@@ -554,7 +571,7 @@ def _build_sim(p: SprayProgram, policy: str, fault_jitter: float):
         uf, us, ud, jm = _draws_jnp(p, key)
         # Mirrors `_jitter_windows_np` op for op, with the same division
         # barriers so XLA cannot FMA-contract the scale arithmetic.
-        inv = _inv_fj(fj)
+        inv = _opaque(_inv_fj(fj))
         fs = jnp.maximum(0.0, FS * (1.0 + uf[..., 0] / inv))
         fe = fs + (FE - FS) / (1.0 / (1.0 + uf[..., 1] / inv))
         dss = jnp.maximum(0.0, DSS * (1.0 + us[..., 0] / inv))
@@ -733,9 +750,11 @@ def spray_single(p: SprayProgram, *, base_seed: int = 0, seed_index: int = 0,
                  fault_jitter: float = 0.0) -> Tuple[float, ...]:
     """One independently-jitted seed:
     `(throughput, healing_s, bytes_ok, lost, makespan)`. Exact-equal to the
-    matching lane of `spray_sweep` (pinned in tests/test_mc_sweep.py)."""
+    matching lane of `spray_sweep` on the CPU (pinned in
+    tests/test_mc_sweep.py); on a TPU, whose float64 is emulated, the two
+    programs may round differently (ROADMAP A4)."""
     single, _ = _sim_fns(p, policy, fault_jitter)
-    with _x64():
+    with x64():
         out = single(_seed_key(base_seed, seed_index))
         return tuple(float(np.asarray(v)) for v in out)
 
@@ -749,7 +768,7 @@ def spray_sweep(p: SprayProgram, n_seeds: int, *, base_seed: int = 0,
     import jax.numpy as jnp
 
     _, sweep = _sim_fns(p, policy, fault_jitter)
-    with _x64():
+    with x64():
         keys = jnp.stack(
             [_seed_key(base_seed, i) for i in range(n_seeds)])
         out = sweep(keys)

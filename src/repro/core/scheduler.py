@@ -377,7 +377,7 @@ def tent_choose_wave(queued, global_local, global_remote, bandwidth, beta0,
 # the numpy wave kernel, for batch scoring in the JAX-side serving planner
 # and accelerator-resident scheduling experiments. Note: bit-exact parity
 # with the float64 scalar path requires running these under
-# `jax.experimental.enable_x64` (the parity tests do); at float32 the gamma
+# `jit_core.x64()` (the parity tests do); at float32 the gamma
 # window can round differently on exact ties.
 # ---------------------------------------------------------------------------
 
@@ -494,7 +494,7 @@ def tent_on_complete_many_jnp(beta0, beta1, queued, ewma_service, completions,
     completion at a time with `.at[slot]` scatters, so repeated slots within
     a batch see exactly the sequential per-slot recurrence the scalar
     `LinkTelemetry.on_complete` produces (parity is bit-exact under
-    `jax.experimental.enable_x64`, like the other kernels in this section).
+    `jit_core.x64()`, like the other kernels in this section).
     Array arguments are full per-slot state vectors; `slots`/`lengths`/
     `queued_at`/`t_obs` describe the batch in drain order. Returns the
     updated `(beta0, beta1, queued, ewma_service, completions)` arrays."""
@@ -563,8 +563,7 @@ def tent_choose_wave_padded_jnp(queued, global_local, global_remote, bandwidth,
     nothing, leave the round-robin counter untouched, and emit
     choice -1 / queued_at 0 — the caller slices them off. On the valid
     prefix the outputs are bit-identical to the unpadded twin, and
-    therefore to the numpy `tent_choose_wave`, under
-    `jax.experimental.enable_x64`."""
+    therefore to the numpy `tent_choose_wave`, under `jit_core.x64()`."""
     import jax
     import jax.numpy as jnp
 

@@ -19,17 +19,6 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..sharding.ctx import constrain
 
-# jax.shard_map landed in 0.6 (with check_vma); older installs only have
-# jax.experimental.shard_map.shard_map (with check_rep).
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is not None:
-    _SHARD_MAP_KW = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
 def route(
     x: jax.Array, router_w: jax.Array, k: int
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
@@ -191,7 +180,7 @@ def moe_ffn_ep(
         return out, lb, zl
 
     bspec = P(b if b else None, None)
-    out, lb, zl = _shard_map(
+    out, lb, zl = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -202,7 +191,7 @@ def moe_ffn_ep(
             P("model", None, "data" if d_data else None),
         ),
         out_specs=(bspec, P(), P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return out, {"lb_loss": lb, "z_loss": zl, "dropped": jnp.zeros((), jnp.int32)}
 

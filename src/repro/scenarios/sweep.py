@@ -22,7 +22,8 @@ staged hops, substitution chains, churn, or app callbacks keep the
 event-driven single-seed `ScenarioRunner` path. Determinism contract (pinned
 in tests/test_mc_sweep.py): same spec + seed vector => byte-identical
 `SweepReport`, and every vmapped per-seed lane is exact-equal to an
-independent single-seed run.
+independent single-seed run on the CPU (on a TPU, whose float64 is
+emulated, the two programs may round differently: ROADMAP A4).
 """
 from __future__ import annotations
 

@@ -3,9 +3,10 @@
 A PrefillWorker runs the real JAX model on the prompt and produces a decode
 cache; the cache bytes are shipped to the DecodeWorker's node through one
 declarative TENT batch (this is the PD-disaggregation elephant flow); the
-DecodeWorker then generates tokens with the real model. Used by the
-end-to-end example and integration tests at smoke scale — numerically
-identical to monolithic generation, by construction and by test.
+DecodeWorker then generates tokens with the real model. Numerically
+identical to monolithic generation, by construction and by test; the
+examples and tests run it at smoke scale, `chip_smoke.py` at a published
+config on a TPU.
 """
 from __future__ import annotations
 
@@ -18,7 +19,12 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..core import Location, MemoryKind, TentEngine
-from ..models import decode_step, init_cache, prefill
+from ..models import decode_step, prefill
+
+# Compiled once per (config, shapes); the weights are arguments, never
+# constants baked into the program.
+prefill_jit = jax.jit(prefill, static_argnums=(0, 3))
+decode_step_jit = jax.jit(decode_step, static_argnums=0)
 
 
 def tree_to_bytes(tree: Any) -> Tuple[np.ndarray, List[Tuple[tuple, str]]]:
@@ -45,6 +51,7 @@ class DisaggResult:
     tokens: np.ndarray  # (B, n_new)
     kv_transfer_seconds: float
     kv_bytes: int
+    kv_segment_id: int  # decode-side segment the cache bytes arrived in
 
 
 class DisaggregatedServer:
@@ -84,10 +91,9 @@ class DisaggregatedServer:
     def generate(self, prompt: jax.Array, n_new: int, max_len: int,
                  enc_frames: jax.Array | None = None,
                  *, async_handoff: bool = False) -> DisaggResult:
-        B, S = prompt.shape
         # ---- prefill pool ----
-        last_logits, cache = prefill(self.cfg, self.params, prompt, max_len,
-                                     enc_frames=enc_frames)
+        last_logits, cache = prefill_jit(self.cfg, self.params, prompt, max_len,
+                                         enc_frames=enc_frames)
         # ---- ship the cache through TENT ----
         data, _ = tree_to_bytes(cache)
         t0 = self.engine.fabric.now
@@ -109,29 +115,29 @@ class DisaggregatedServer:
         secs = self.engine.fabric.now - t0
         cache = bytes_to_tree(dst.read(0, data.size), cache)
         # ---- decode pool ----
-        tok = jnp.argmax(last_logits, axis=-1)[:, None].astype(jnp.int32)
-        out = [np.asarray(tok)]
-        step = jax.jit(lambda c, t, p: decode_step(self.cfg, self.params, c, t, p))
-        for i in range(n_new - 1):
-            logits, cache = step(cache, tok, jnp.int32(S + i))
-            tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-            out.append(np.asarray(tok))
         return DisaggResult(
-            tokens=np.concatenate(out, axis=1),
+            tokens=_greedy_decode(self.cfg, self.params, cache, last_logits,
+                                  prompt.shape[1], n_new),
             kv_transfer_seconds=secs,
             kv_bytes=int(data.size),
+            kv_segment_id=dst.segment_id,
         )
+
+
+def _greedy_decode(cfg: ModelConfig, params: Any, cache: Any, last_logits: jax.Array,
+                   start: int, n_new: int) -> np.ndarray:
+    """`n_new` greedy tokens: the argmax of the prefill logits, then one
+    decode step per further token from position `start`."""
+    tok = jnp.argmax(last_logits, axis=-1)[:, None].astype(jnp.int32)
+    out = [np.asarray(tok)]
+    for i in range(n_new - 1):
+        logits, cache = decode_step_jit(cfg, params, cache, tok, jnp.int32(start + i))
+        tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+        out.append(np.asarray(tok))
+    return np.concatenate(out, axis=1)
 
 
 def monolithic_generate(cfg: ModelConfig, params: Any, prompt: jax.Array, n_new: int,
                         max_len: int, enc_frames: jax.Array | None = None) -> np.ndarray:
-    B, S = prompt.shape
-    last_logits, cache = prefill(cfg, params, prompt, max_len, enc_frames=enc_frames)
-    tok = jnp.argmax(last_logits, axis=-1)[:, None].astype(jnp.int32)
-    out = [np.asarray(tok)]
-    step = jax.jit(lambda c, t, p: decode_step(cfg, params, c, t, p))
-    for i in range(n_new - 1):
-        logits, cache = step(cache, tok, jnp.int32(S + i))
-        tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-        out.append(np.asarray(tok))
-    return np.concatenate(out, axis=1)
+    last_logits, cache = prefill_jit(cfg, params, prompt, max_len, enc_frames=enc_frames)
+    return _greedy_decode(cfg, params, cache, last_logits, prompt.shape[1], n_new)

@@ -6,7 +6,7 @@ the wave and drain vectorizations before it (PRs 4-5), the jitted core must
 be a pure *cost* change: with the toggle on, every scenario outcome — byte
 counts, makespans, latency percentiles, retries, per-rail byte maps — has
 to be bit-identical to the numpy path, because both run the same IEEE
-double operations in the same order under `enable_x64`. These tests pin
+double operations in the same order under `jit_core.x64()`. These tests pin
 that end-to-end across the whole scenario library (including the mid-run
 fault-window scenarios), force the crossover to both extremes, and pin the
 padded kernels against the scalar references with seeded randomized sweeps
@@ -145,9 +145,9 @@ def _run_padded_choose(args, rr, gamma):
 
     valid = np.zeros(ps, dtype=bool)
     valid[:n_s] = True
-    from jax.experimental import enable_x64
+    from repro.core.jit_core import x64
 
-    with enable_x64():
+    with x64():
         c, qa, qo, rro = tent_choose_wave_padded_jnp(
             pad(q, pc, 0.0), pad(gl, pc, 0.0), pad(gr, pc, 0.0),
             pad(bw, pc, 1.0), pad(b0, pc, 0.0), pad(b1, pc, 1.0),

@@ -21,6 +21,7 @@ from repro.serving import (
     make_gpu_pool,
     monolithic_generate,
 )
+from repro.serving.disagg import prefill_jit, tree_to_bytes
 from repro.training import flatten_state
 
 
@@ -133,3 +134,6 @@ class TestDisaggregation:
         np.testing.assert_array_equal(res.tokens, ref)
         assert res.kv_transfer_seconds > 0
         assert res.kv_bytes > 0
+        _, cache = prefill_jit(cfg, params, prompt, 32)
+        received = server.engine.segments.get(res.kv_segment_id).read(0, res.kv_bytes)
+        np.testing.assert_array_equal(received, tree_to_bytes(cache)[0])
