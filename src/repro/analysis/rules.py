@@ -84,10 +84,13 @@ class NoWallClock(Rule):
     FORBIDDEN_SUFFIXES = ("datetime.now", "datetime.utcnow", "date.today")
 
     # Modules whose purpose is wall-clock measurement (never on a simulated
-    # path): the real-training step timer and the XLA compile-time probe.
+    # path): the real-training step timer, the XLA compile-time probe, and
+    # the host spans of the real serving path (which read the clock only
+    # while a recorder is attached).
     ALLOWED_FILES = {
         "src/repro/training/train_loop.py",
         "src/repro/launch/dryrun.py",
+        "src/repro/obs/spans.py",
     }
 
     def check_file(self, ctx: FileContext, project: Project):

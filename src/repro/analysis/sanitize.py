@@ -45,6 +45,7 @@ class SanitizerError(RuntimeError):
 DYNAMIC_ALLOWLIST = frozenset({
     "repro.training.train_loop",
     "repro.launch.dryrun",
+    "repro.obs.spans",
 })
 
 _ENV_VAR = "REPRO_SANITIZE"

@@ -253,7 +253,6 @@ class TentEngine:
         self._post_buffer: Optional[list] = None
         self._cb_batches = 0  # live batches with registered done-callbacks
         # observability
-        self.slice_latencies: List[float] = []
         self.transfer_records: List[BatchResult] = []
         self.slices_retried = 0
         self.backend_substitutions = 0
@@ -266,6 +265,9 @@ class TentEngine:
         # (wave / drain run / declared intent), never per slice — the
         # zero-cost-when-off contract the hot-path bench gates pin.
         self._rec = None
+        # host spans (repro.obs.spans.HostSpans): None = off, with the same
+        # contract — one guard per transfer, wave or drain run
+        self._spans = None
         if self.config.wave_complete:
             self.fabric.register_completion_sink(
                 self._on_wire_done, self._on_wire_done_many)
@@ -304,6 +306,20 @@ class TentEngine:
                 "engine (record sites cannot run under jit)",
                 RuntimeWarning, stacklevel=2)
             self._jit = None
+
+    def attach_spans(self, rec) -> None:
+        """Attach a `repro.obs.spans.HostSpans` (None detaches). Each
+        `transfer_sync` / `run_until_idle` then records a
+        `tent.engine.transfer` span whose attrs are the growth of
+        `slices_issued`, `waves`, `completions_drained` and
+        `completion_batches` and the `bytes` of the batches it completed;
+        inside it, every `_issue_wave` a `tent.engine.wave` (attr `slices`)
+        and every batched drain a `tent.engine.drain` (attrs `slices`,
+        `bytes`), where the slices' bytes are copied. The scalar paths
+        record nothing (that would be a span per slice): their time is the
+        transfer's own. Spans run outside jitted code, so unlike the flight
+        recorder they leave the jitted core on."""
+        self._spans = rec
 
     def register_metrics(self, reg) -> None:
         """Expose the engine's scheduling counters as lazy gauges on a
@@ -413,12 +429,37 @@ class TentEngine:
         return self._result(bc)
 
     def run_until_idle(self) -> None:
-        self.fabric.run_until_idle()
+        sp = self._spans
+        if sp is None:
+            self.fabric.run_until_idle()
+        else:
+            self._in_transfer_span(sp, self.fabric.run_until_idle)
 
     def transfer_sync(self, src: int, soff: int, dst: int, doff: int, length: int) -> BatchResult:
+        sp = self._spans
+        if sp is None:
+            return self._transfer_sync(src, soff, dst, doff, length)
+        return self._in_transfer_span(sp, self._transfer_sync, src, soff, dst, doff, length)
+
+    def _transfer_sync(self, src: int, soff: int, dst: int, doff: int, length: int) -> BatchResult:
         b = self.allocate_batch()
         self.submit_transfer(b, [(src, soff, dst, doff, length)])
         return self.wait(b)
+
+    def _in_transfer_span(self, sp, fn, *args):
+        """`fn(*args)` inside a `tent.engine.transfer` span (`attach_spans`)."""
+        issued, waves = self.slices_issued, self.waves
+        drained, batches = self.completions_drained, self.completion_batches
+        done = len(self.transfer_records)
+        with sp.span("tent.engine.transfer") as attrs:
+            out = fn(*args)
+            attrs.update(
+                slices_issued=self.slices_issued - issued,
+                waves=self.waves - waves,
+                completions_drained=self.completions_drained - drained,
+                completion_batches=self.completion_batches - batches,
+                bytes=sum(r.bytes for r in self.transfer_records[done:]))
+        return out
 
     def _result(self, bc: _BatchCB) -> BatchResult:
         return BatchResult(
@@ -460,7 +501,13 @@ class TentEngine:
                 wave.append((sl, tcb))
             if not wave:
                 return
-            self._issue_wave(wave)
+            sp = self._spans
+            if sp is None:
+                self._issue_wave(wave)
+            else:
+                sp.open("tent.engine.wave", slices=len(wave))
+                self._issue_wave(wave)
+                sp.close()
 
     def _stage_cands(self, tcb: _TransferCB, hop: int) -> StageCandidates:
         """The candidate set for a transfer's current (route, hop) stage,
@@ -849,6 +896,10 @@ class TentEngine:
         n = len(ops)
         self.completions_drained += n
         self.completion_batches += 1
+        sp = self._spans
+        if sp is not None:
+            sp.open("tent.engine.drain", slices=n,
+                    bytes=sum(op.tag.sl.length for op in ops))
         if self._adaptive_wave_min:
             self._drain_ewma = 0.75 * self._drain_ewma + 0.25 * n
             self._tune_wave_min()
@@ -923,6 +974,8 @@ class TentEngine:
             else:
                 self._drain_success_run(run, now)
             i = j
+        if sp is not None:
+            sp.close()
 
     @hot_path
 
@@ -1026,7 +1079,6 @@ class TentEngine:
             dst_seg.write(sl.dst_offset, src_seg.read(sl.src_offset, sl.length))
         sl.state = SliceState.DONE
         sl.completed_at = t_end
-        self.slice_latencies.append(t_end - sl.submitted_at)
         tcb.remaining -= 1
         bc = self._batches[tcb.batch_id]
         bc.remaining_slices -= 1
@@ -1151,11 +1203,6 @@ class TentEngine:
                 out["batches_open"] += 1
                 out["slices_outstanding"] += bc.remaining_slices
         return out
-
-    def percentile_latency(self, q: float) -> float:
-        if not self.slice_latencies:
-            return 0.0
-        return float(np.percentile(np.asarray(self.slice_latencies), q))
 
     def bytes_by_link(self) -> Dict[int, int]:
         return self.fabric.bytes_by_link()

@@ -20,6 +20,7 @@ import numpy as np
 from ..configs.base import ModelConfig
 from ..core import Location, MemoryKind, TentEngine
 from ..models import decode_step, prefill
+from ..obs.spans import span
 
 # Compiled once per (config, shapes); the weights are arguments, never
 # constants baked into the program.
@@ -69,6 +70,23 @@ class DisaggregatedServer:
                                numa=spec.node.gpu_numa(0))
         self._loc_d = Location(node=decode_node, kind=MemoryKind.DEVICE_HBM, device=0,
                                numa=spec.node.gpu_numa(0))
+        self._spans = None  # repro.obs.spans.HostSpans; None = off
+
+    def attach_spans(self, rec) -> None:
+        """Attach a `repro.obs.spans.HostSpans` (None detaches) to this
+        server and its engine; `generate` then records the spans its
+        docstring lists."""
+        self._spans = rec
+        self.engine.attach_spans(rec)
+
+    def _kv_segments(self, data: np.ndarray):
+        """Register the handoff's source and destination segments and
+        write the cache bytes into the source."""
+        nbytes = max(data.size, 1)
+        src = self.engine.register_segment(self._loc_p, nbytes, name="kv-src")
+        dst = self.engine.register_segment(self._loc_d, nbytes, name="kv-dst")
+        src.write(0, data)
+        return src, dst, nbytes
 
     def ship_kv_async(self, data: np.ndarray, on_done=None) -> Tuple[Any, int]:
         """Declarative KV-handoff intent: post the prefill->decode elephant
@@ -77,10 +95,7 @@ class DisaggregatedServer:
         instead of the prefill side blocking on the wire. The closed-loop
         serving simulator and `generate(async_handoff=True)` both ride this.
         """
-        nbytes = max(data.size, 1)
-        src = self.engine.register_segment(self._loc_p, nbytes, name="kv-src")
-        dst = self.engine.register_segment(self._loc_d, nbytes, name="kv-dst")
-        src.write(0, data)
+        src, dst, nbytes = self._kv_segments(data)
         batch = self.engine.allocate_batch()
         self.engine.submit_transfer(
             batch, [(src.segment_id, 0, dst.segment_id, 0, nbytes)])
@@ -91,33 +106,68 @@ class DisaggregatedServer:
     def generate(self, prompt: jax.Array, n_new: int, max_len: int,
                  enc_frames: jax.Array | None = None,
                  *, async_handoff: bool = False) -> DisaggResult:
-        # ---- prefill pool ----
-        last_logits, cache = prefill_jit(self.cfg, self.params, prompt, max_len,
-                                         enc_frames=enc_frames)
-        # ---- ship the cache through TENT ----
-        data, _ = tree_to_bytes(cache)
-        t0 = self.engine.fabric.now
-        if async_handoff:
-            # intent mode: the batch is posted and the decode worker starts
-            # when the completion callback lands (here: drain the fabric —
-            # the real decode numerics need the full cache)
-            done = {}
-            dst, _ = self.ship_kv_async(
-                data, lambda res: done.setdefault("res", res))
-            self.engine.run_until_idle()
-            res = done["res"]
-        else:
-            src = self.engine.register_segment(self._loc_p, max(data.size, 1), name="kv-src")
-            dst = self.engine.register_segment(self._loc_d, max(data.size, 1), name="kv-dst")
-            src.write(0, data)
-            res = self.engine.transfer_sync(src.segment_id, 0, dst.segment_id, 0, max(data.size, 1))
-        assert res.ok, res.error
-        secs = self.engine.fabric.now - t0
-        cache = bytes_to_tree(dst.read(0, data.size), cache)
-        # ---- decode pool ----
+        """Prefill, ship the cache through TENT, decode `n_new` tokens.
+
+        With a `HostSpans` attached (`attach_spans`) one call records
+        `tent.generate` (attrs `call`, `batch`, `prompt_len`, `n_new`) and,
+        in order, its children:
+
+        - `tent.prefill`: the dispatch of the prefill program. Asynchronous:
+          the device may still run it when the span closes, and the wait
+          then lands in `tent.kv.pack`.
+        - `tent.kv.pack`: `tree_to_bytes`, the device-to-host copy of the
+          cache.
+        - `tent.kv.segments`: both segments registered and the bytes
+          written into the source (with `async_handoff`, all of
+          `ship_kv_async`, which also posts the batch's first wave).
+        - `tent.kv.spray`: `transfer_sync` (or `run_until_idle`), holding
+          the engine's `tent.engine.transfer`.
+        - `tent.kv.read`: the bytes read back from the decode segment.
+        - `tent.kv.unpack`: `bytes_to_tree`. Asynchronous: it returns once
+          the host-to-device copies are enqueued.
+        - `tent.decode`: the greedy decode, with `tent.decode.step` and
+          `tent.decode.fetch` per step (see `_greedy_decode`).
+        """
+        sp = self._spans
+        with span(sp, "tent.generate", new_call=True, batch=int(prompt.shape[0]),
+                  prompt_len=int(prompt.shape[1]), n_new=int(n_new)):
+            # ---- prefill pool ----
+            with span(sp, "tent.prefill"):
+                last_logits, cache = prefill_jit(self.cfg, self.params, prompt, max_len,
+                                                 enc_frames=enc_frames)
+            # ---- ship the cache through TENT ----
+            with span(sp, "tent.kv.pack"):
+                data, _ = tree_to_bytes(cache)
+            t0 = self.engine.fabric.now
+            if async_handoff:
+                # intent mode: the batch is posted and the decode worker
+                # starts when the completion callback lands (here: drain the
+                # fabric — the real decode numerics need the full cache)
+                done = {}
+                with span(sp, "tent.kv.segments"):
+                    dst, _ = self.ship_kv_async(
+                        data, lambda res: done.setdefault("res", res))
+                with span(sp, "tent.kv.spray"):
+                    self.engine.run_until_idle()
+                res = done["res"]
+            else:
+                with span(sp, "tent.kv.segments"):
+                    src, dst, nbytes = self._kv_segments(data)
+                with span(sp, "tent.kv.spray"):
+                    res = self.engine.transfer_sync(src.segment_id, 0, dst.segment_id,
+                                                    0, nbytes)
+            assert res.ok, res.error
+            secs = self.engine.fabric.now - t0
+            with span(sp, "tent.kv.read"):
+                shipped = dst.read(0, data.size)
+            with span(sp, "tent.kv.unpack"):
+                cache = bytes_to_tree(shipped, cache)
+            # ---- decode pool ----
+            with span(sp, "tent.decode"):
+                tokens = _greedy_decode(self.cfg, self.params, cache, last_logits,
+                                        prompt.shape[1], n_new, spans=sp)
         return DisaggResult(
-            tokens=_greedy_decode(self.cfg, self.params, cache, last_logits,
-                                  prompt.shape[1], n_new),
+            tokens=tokens,
             kv_transfer_seconds=secs,
             kv_bytes=int(data.size),
             kv_segment_id=dst.segment_id,
@@ -125,15 +175,20 @@ class DisaggregatedServer:
 
 
 def _greedy_decode(cfg: ModelConfig, params: Any, cache: Any, last_logits: jax.Array,
-                   start: int, n_new: int) -> np.ndarray:
+                   start: int, n_new: int, *, spans=None) -> np.ndarray:
     """`n_new` greedy tokens: the argmax of the prefill logits, then one
-    decode step per further token from position `start`."""
+    decode step per further token from position `start`. With `spans`, each
+    step records `tent.decode.step` (the dispatch of the decode program and
+    the argmax, asynchronous) and `tent.decode.fetch` (the token copied to
+    the host, which waits for the device to finish the step)."""
     tok = jnp.argmax(last_logits, axis=-1)[:, None].astype(jnp.int32)
     out = [np.asarray(tok)]
     for i in range(n_new - 1):
-        logits, cache = decode_step_jit(cfg, params, cache, tok, jnp.int32(start + i))
-        tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-        out.append(np.asarray(tok))
+        with span(spans, "tent.decode.step"):
+            logits, cache = decode_step_jit(cfg, params, cache, tok, jnp.int32(start + i))
+            tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+        with span(spans, "tent.decode.fetch"):
+            out.append(np.asarray(tok))
     return np.concatenate(out, axis=1)
 
 

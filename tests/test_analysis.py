@@ -45,6 +45,19 @@ class TestRuleFixtures:
         assert lint_fixture("clean_wall_clock.py",
                             rules=["no-wall-clock"]) == []
 
+    @pytest.mark.parametrize("rel, flagged", [
+        ("src/repro/obs/spans.py", 0),  # the host spans' one clock
+        ("src/repro/obs/recorder.py", 1),
+        ("src/repro/serving/disagg.py", 1),
+        ("src/repro/core/engine.py", 1),
+    ])
+    def test_no_wall_clock_allows_host_spans_only(self, tmp_path, rel, flagged):
+        f = tmp_path / rel
+        f.parent.mkdir(parents=True)
+        f.write_text("import time\n\n\ndef g():\n    return time.perf_counter_ns()\n")
+        found = run_rules(Project(tmp_path, [f]), default_rules(["no-wall-clock"]))
+        assert len(found) == flagged
+
     def test_no_global_rng_flags_bad_fixture(self):
         found = lint_fixture("bad_global_rng.py", rules=["no-global-rng"])
         assert {f.rule for f in found} == {"no-global-rng"}
@@ -302,9 +315,11 @@ class TestSanitizer:
             assert isinstance(time.time(), float)  # this module isn't repro.*
             assert np.random.default_rng(0).random() >= 0  # always fine
 
-    def test_allowlisted_repro_module_passes(self):
+    @pytest.mark.parametrize("module", [
+        "repro.training.train_loop", "repro.launch.dryrun", "repro.obs.spans"])
+    def test_allowlisted_repro_module_passes(self, module):
         import time
-        ns = {"__name__": "repro.training.train_loop", "time": time}
+        ns = {"__name__": module, "time": time}
         exec("def f():\n    return time.time()", ns)
         with sanitized():
             assert isinstance(ns["f"](), float)
