@@ -159,17 +159,46 @@ def attend_chunked(
     return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, Hd)
 
 
+# The serving prefill's budget for one layer's float32 score block. At B=16
+# and 14 heads a whole 2048-token block would be 3.76 GB, on a 16 GB chip.
+PREFILL_SCORE_BYTES = 1 << 30
+
+
 def prefill_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, *, window: int = 0, use_pallas: bool = False
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    window: int = 0,
+    use_pallas: bool = False,
+    score_bytes: Optional[int] = None,
 ) -> jax.Array:
+    """Causal self-attention over a prompt. With `score_bytes` None the
+    rule goes by length: query chunks of CHUNK_Q past CHUNKED_THRESHOLD
+    where S divides into them (training and the dry-run). Otherwise by
+    bytes, for every S: the whole float32 score block (B*H*S*S*4) where it
+    fits `score_bytes`, else the largest power-of-two chunk up to CHUNK_Q
+    whose block (B*H*chunk*S_padded*4) fits, the prompt padded at its end
+    to whole chunks. Causal masking keeps the padded keys from every real
+    query, and the padded queries are sliced off."""
     if use_pallas:
         from repro.kernels.flash_attention.ops import flash_attention
 
         return flash_attention(q, k, v, causal=True, window=window)
-    S = q.shape[1]
-    if S > CHUNKED_THRESHOLD and S % CHUNK_Q == 0:
-        return attend_chunked(q, k, v, causal=True, window=window)
-    return attend_full(q, k, v, causal=True, window=window)
+    B, S, H, _ = q.shape
+    if score_bytes is None:
+        if S > CHUNKED_THRESHOLD and S % CHUNK_Q == 0:
+            return attend_chunked(q, k, v, causal=True, window=window)
+        return attend_full(q, k, v, causal=True, window=window)
+    if B * H * S * S * 4 <= score_bytes:
+        return attend_full(q, k, v, causal=True, window=window)
+    chunk = CHUNK_Q
+    while chunk > 1 and B * H * chunk * (-(-S // chunk) * chunk) * 4 > score_bytes:
+        chunk //= 2
+    if S % chunk:
+        pad = ((0, 0), (0, -S % chunk), (0, 0), (0, 0))
+        q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
+    return attend_chunked(q, k, v, causal=True, window=window, chunk=chunk)[:, :S]
 
 
 def cache_update(

@@ -17,6 +17,14 @@ size depth-independent (88- and 94-layer configs compile quickly even on a
   loss_fn                         token CE + MoE aux losses
   init_cache / cache_shapes       decode caches (concrete / abstract)
   decode_step                     single-token serve step
+  prefill                         serving prefill: last logits + decode cache
+                                  (one parallel causal pass where
+                                  `prefill_path` says it is exact, else the
+                                  replay)
+  prefill_replay                  the reference prefill: decode_step scanned
+                                  over the prompt
+  prefill_forward                 the parallel pass's own cache layout (what
+                                  the dry-run lowers for prefill shapes)
   encode                          audio encoder (enc-dec only)
 """
 from __future__ import annotations
@@ -28,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from . import attention as attn_lib
 from . import moe as moe_lib
 from . import ssm as ssm_lib
 from .attention import (
@@ -203,7 +212,8 @@ def _ring_cache(k: jax.Array, window: int) -> jax.Array:
     return out.at[:, slots].set(last)
 
 
-def _decoder_layer_train(cfg: ModelConfig, lp, x, enc_out, positions, collect_cache=False):
+def _decoder_layer_train(cfg: ModelConfig, lp, x, enc_out, positions, collect_cache=False,
+                         attend=prefill_attention):
     aux = {}
     cache_out = {}
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
@@ -213,7 +223,7 @@ def _decoder_layer_train(cfg: ModelConfig, lp, x, enc_out, positions, collect_ca
             cache_out = {"ssm_state": st.astype(jnp.float32), "conv_buf": conv_tail}
         return x + y, aux, cache_out
     q, k, v = _project_qkv(cfg, lp, h, positions)
-    a = prefill_attention(q, k, v, window=cfg.sliding_window, use_pallas=cfg.use_pallas)
+    a = attend(q, k, v, window=cfg.sliding_window, use_pallas=cfg.use_pallas)
     a = constrain(a, "bshd")
     attn = jnp.einsum("bse,ed->bsd", a.reshape(a.shape[0], a.shape[1], -1), lp["wo"])
     if collect_cache:
@@ -338,12 +348,7 @@ def prefill_forward(
     if cfg.is_encdec:
         assert enc_frames is not None
         enc_out = encode(cfg, params, enc_frames)
-
-    def body(x, lp):
-        x, _, cache = _decoder_layer_train(cfg, lp, x, enc_out, positions, collect_cache=True)
-        return x, cache
-
-    x, cache = jax.lax.scan(body, x, params["layers"])
+    x, cache = _prefill_layers(cfg, params, x, positions, enc_out, prefill_attention)
     if cfg.is_encdec and enc_out is not None:
         K, Hd = cfg.num_kv_heads, cfg.resolved_head_dim
         lp = params["layers"]
@@ -354,6 +359,24 @@ def prefill_forward(
         cache["enc_v"] = jnp.einsum("bsd,lde->lbse", enc_out, lp["xwv"]).reshape(
             cfg.num_layers, B, enc_len, K, Hd
         )
+    return _last_logits(cfg, params, x), cache
+
+
+def _prefill_layers(cfg: ModelConfig, params: Params, x: jax.Array, positions, enc_out,
+                    attend):
+    """The decoder layers over the embedded prompt `x` (B, S, D) in one
+    causal pass: the last layer's output and the per-layer caches
+    `_decoder_layer_train` collects."""
+    def body(x, lp):
+        x, _, cache = _decoder_layer_train(cfg, lp, x, enc_out, positions,
+                                           collect_cache=True, attend=attend)
+        return x, cache
+
+    return jax.lax.scan(body, x, params["layers"])
+
+
+def _last_logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
+    """The (B, V) float32 logits of the last position of `x` (B, S, D)."""
     x_last = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head")
     if head is None:
@@ -361,7 +384,7 @@ def prefill_forward(
     else:
         logits = jnp.einsum("bsd,dv->bsv", x_last, head).astype(jnp.float32)
     logits = constrain(logits, "logits")
-    return logits[:, 0], cache
+    return logits[:, 0]
 
 
 def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]):
@@ -484,6 +507,19 @@ def decode_step(
     return logits[:, 0], new_cache
 
 
+def prefill_path(cfg: ModelConfig) -> str:
+    """Which prefill `prefill` runs for `cfg`: "parallel" where one causal
+    pass over the prompt computes what the replay computes, rounding order
+    aside (attention-only decoders without experts), "replay" elsewhere: an
+    SSM's chunked scan is another computation than its step recurrence, an
+    expert dispatch with capacity over the whole prompt routes otherwise
+    than token by token, and the encoder-decoder keeps the replay."""
+    if (cfg.arch_type in ("dense", "vlm") and cfg.num_experts == 0
+            and not cfg.hybrid and not cfg.is_encdec):
+        return "parallel"
+    return "replay"
+
+
 def prefill(
     cfg: ModelConfig,
     params: Params,
@@ -492,13 +528,44 @@ def prefill(
     *,
     enc_frames: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Run the full prompt through the model and build a decode cache by
-    replaying tokens through decode_step's cache layout. For full-attention
-    archs this populates K/V; for SSM it folds the prompt into the state.
+    """Serving prefill: the (B, V) float32 logits of the prompt's last
+    position and a decode cache laid out as `init_cache(cfg, B, max_len)`
+    lays it out. Where `prefill_path(cfg)` is "parallel", one causal pass
+    over the prompt whose K/V fill the cache's first S positions (zeros
+    beyond, as the replay leaves them); elsewhere `prefill_replay`."""
+    if prefill_path(cfg) == "replay":
+        return prefill_replay(cfg, params, tokens, max_len, enc_frames=enc_frames)
+    S = tokens.shape[1]
+    W = cache_shapes(cfg, 1, max_len)["k"].shape[2]  # the decode cache's width
+    if cfg.sliding_window > 0:
+        # the decode ring has W slots and attends to all of them once full,
+        # so the replay's window is W where max_len is below the window
+        cfg = cfg.with_(sliding_window=W)
+    elif S > W:
+        raise ValueError(f"prompt of {S} tokens does not fit max_len {max_len}")
+    x = constrain(params["embed"][tokens], "bsd")
+    attend = functools.partial(prefill_attention, score_bytes=attn_lib.PREFILL_SCORE_BYTES)
+    x, cache = _prefill_layers(cfg, params, x, jnp.arange(S), None, attend)
+    logits = _last_logits(cfg, params, x)
+    dtype = params["embed"].dtype
+    # a window's K/V arrive in the ring's order and width; the rest is padded
+    pad = ((0, 0), (0, 0), (0, W - cache["k"].shape[2]), (0, 0), (0, 0))
+    return logits, {kv: jnp.pad(cache[kv].astype(dtype), pad) for kv in ("k", "v")}
 
-    This is the *functional* prefill used by tests and the serving example;
-    the dry-run lowers `forward` for prefill shapes (cache construction is
-    measured by decode shapes)."""
+
+def prefill_replay(
+    cfg: ModelConfig,
+    params: Params,
+    tokens: jax.Array,
+    max_len: int,
+    *,
+    enc_frames: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The reference prefill: the prompt replayed token by token through
+    `decode_step` into an `init_cache` cache. For attention archs this
+    populates K/V; for SSM it folds the prompt into the state. `prefill`
+    serves through it where `prefill_path` is "replay", and the tests hold
+    the parallel pass to it."""
     B, S = tokens.shape
     enc_len = enc_frames.shape[1] if enc_frames is not None else 0
     cache = init_cache(cfg, B, max_len, enc_len, dtype=params["embed"].dtype)

@@ -19,7 +19,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..core import Location, MemoryKind, TentEngine
-from ..models import decode_step, prefill
+from ..models import decode_step, prefill, prefill_path
 from ..obs.spans import span
 
 # Compiled once per (config, shapes); the weights are arguments, never
@@ -112,9 +112,10 @@ class DisaggregatedServer:
         `tent.generate` (attrs `call`, `batch`, `prompt_len`, `n_new`) and,
         in order, its children:
 
-        - `tent.prefill`: the dispatch of the prefill program. Asynchronous:
-          the device may still run it when the span closes, and the wait
-          then lands in `tent.kv.pack`.
+        - `tent.prefill` (attr `path`, `prefill_path(cfg)`: which prefill
+          served the call): the dispatch of the prefill program.
+          Asynchronous: the device may still run it when the span closes,
+          and the wait then lands in `tent.kv.pack`.
         - `tent.kv.pack`: `tree_to_bytes`, the device-to-host copy of the
           cache.
         - `tent.kv.segments`: both segments registered and the bytes
@@ -132,7 +133,7 @@ class DisaggregatedServer:
         with span(sp, "tent.generate", new_call=True, batch=int(prompt.shape[0]),
                   prompt_len=int(prompt.shape[1]), n_new=int(n_new)):
             # ---- prefill pool ----
-            with span(sp, "tent.prefill"):
+            with span(sp, "tent.prefill", path=prefill_path(self.cfg)):
                 last_logits, cache = prefill_jit(self.cfg, self.params, prompt, max_len,
                                                  enc_frames=enc_frames)
             # ---- ship the cache through TENT ----

@@ -13,6 +13,7 @@ from repro.models import (
     init_params,
     prefill,
     prefill_forward,
+    prefill_replay,
 )
 from repro.models.moe import moe_ffn_dense, moe_ffn_sorted
 from repro.models.ssm import ssd_chunked, ssd_recurrent_ref
@@ -110,7 +111,8 @@ class TestMoE:
 
 @pytest.mark.slow
 class TestDecodeConsistency:
-    """prefill (decode_step replay) must agree with the parallel forward."""
+    """The replay (decode_step scanned over the prompt) must agree with the
+    parallel forward."""
 
     @pytest.mark.parametrize(
         "arch", ["qwen2-0.5b", "deepseek-7b", "mamba2-370m", "hymba-1.5b", "granite-34b"]
@@ -122,7 +124,7 @@ class TestDecodeConsistency:
         B, S = 2, 16
         tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
         logits_par, _ = forward(cfg, params, tokens)
-        last_dec, _ = prefill(cfg, params, tokens, max_len=32)
+        last_dec, _ = prefill_replay(cfg, params, tokens, max_len=32)
         np.testing.assert_allclose(
             np.asarray(last_dec), np.asarray(logits_par[:, -1]), rtol=2e-3, atol=2e-3
         )
@@ -133,7 +135,7 @@ class TestDecodeConsistency:
         B, S = 1, 24
         tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
         logits_par, _ = forward(cfg, params, tokens)
-        last_dec, _ = prefill(cfg, params, tokens, max_len=cfg.sliding_window)
+        last_dec, _ = prefill_replay(cfg, params, tokens, max_len=cfg.sliding_window)
         np.testing.assert_allclose(
             np.asarray(last_dec), np.asarray(logits_par[:, -1]), rtol=2e-3, atol=2e-3
         )
@@ -148,7 +150,7 @@ class TestDecodeConsistency:
         tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
         max_len = max(S + 1, cfg.sliding_window)
         logits_pf, cache_pf = prefill_forward(cfg, params, tokens)
-        logits_rp, cache_rp = prefill(cfg, params, tokens, max_len=max_len)
+        logits_rp, cache_rp = prefill_replay(cfg, params, tokens, max_len=max_len)
         np.testing.assert_allclose(
             np.asarray(logits_pf), np.asarray(logits_rp), rtol=2e-3, atol=2e-3
         )
