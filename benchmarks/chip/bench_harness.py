@@ -5,7 +5,10 @@ file of check limits), makes the weights on the device from `--seed`,
 serves the mix through `DisaggregatedServer.generate` over a `TentEngine`
 built from the `disagg_prefill_decode` scenario, and times it from outside
 the program: the module-level callables that `generate` looks up at call
-time, and the engine's transfer entry points, are wrapped ("seams").
+time, and the engine's transfer entry points, are wrapped ("seams"). A
+traced run (`--trace 1`) also records the program's own host spans
+(`repro.obs.HostSpans`, where the server has `attach_spans`) for the
+per-layer readers; an untraced run attaches nothing.
 
 The client is one closed loop: the next call is issued when the previous
 one returns. Every call is one batch of `batch` requests of one prompt
@@ -546,6 +549,10 @@ class LayerContext:
     dims: Any
     peaks: Dict[str, Any]
     batch: int
+    # the program's finished host spans of the traced window
+    # (`repro.obs.HostSpans`: (name, t0_ns, t1_ns, parent, call, attrs) in
+    # the order they opened); None where the program has no `attach_spans`
+    spans: Optional[List[tuple]] = None
 
     def program_seconds(self, pattern: str) -> Tuple[float, int]:
         import trace_reduce
@@ -567,6 +574,18 @@ def read_per_layer(cell: Cell, ctx: LayerContext) -> Dict[str, Dict[str, Any]]:
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
+
+
+def attach_spans(server):
+    """A fresh `repro.obs.HostSpans` attached to the server and its
+    engine, where the program has `attach_spans`; None where it has not."""
+    if not callable(getattr(server, "attach_spans", None)):
+        return None
+    from repro.obs import HostSpans
+
+    rec = HostSpans()
+    server.attach_spans(rec)
+    return rec
 
 
 def start_trace(tmp: Path) -> None:
@@ -632,10 +651,15 @@ def _run(cell: Cell, devices, peaks, clock: CompileCount, seed: int, seconds: fl
             start_trace(tmp)
         compiles_before = clock.total()
         setup_s = time.perf_counter() - t_process
+        # the program's own spans in the traced run only: the end-to-end
+        # metrics are measured with them off
+        spans = attach_spans(server) if trace else None
         try:
             served = serve_window(cell, server, seams, seed,
                                   min(seconds, TRACE_SECONDS) if trace else seconds, keep)
         finally:
+            if spans is not None:
+                server.attach_spans(None)
             tr = stop_trace(tmp) if trace else None
             if tmp is not None:
                 shutil.rmtree(tmp, ignore_errors=True)
@@ -655,7 +679,8 @@ def _run(cell: Cell, devices, peaks, clock: CompileCount, seed: int, seconds: fl
 
         lo, hi = trace_reduce.host_window(tr)
         ctx = LayerContext([r for r in records if not r.error], tr, (lo, hi),
-                           cell.arch, cell.arch.dims(cell.config), peaks, batch)
+                           cell.arch, cell.arch.dims(cell.config), peaks, batch,
+                           spans.finished() if spans is not None else None)
         metrics = read_per_layer(cell, ctx)
         extra_device = {"busy_s": ctx.busy_seconds(), "window_s": hi - lo}
         breakdown = {"device_ops": trace_reduce.top_modules(tr, lo, hi),

@@ -11,7 +11,8 @@ planes of a JAX trace). Busy time is the union of the intervals of the
 has no op line), averaged over the devices. A program's device time is the
 sum of its `XLA Modules` events, found by the module's name. Host spans
 are the benchmark's own `jax.profiler.TraceAnnotation`s (names starting
-with `bench.`), on the same clock as the device events.
+with `bench.`) and the program's (`tent.`, `repro.obs.HostSpans`), on the
+same clock as the device events.
 """
 from __future__ import annotations
 
@@ -26,7 +27,9 @@ Interval = Tuple[float, float]  # (start_s, end_s)
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DEVICE_PLANE = re.compile(r"^/device:[A-Za-z]+:\d+$")
-HOST_PREFIX = "bench."
+HOST_PREFIXES = ("bench.", "tent.")
+# spans that frame the others and label no time themselves
+FRAMES = ("bench.window", "bench.call")
 
 
 @dataclass
@@ -59,7 +62,7 @@ def load(path: str) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(HOST_PREFIX):
+                    if e.name.startswith(HOST_PREFIXES):
                         tr.host.append((e.name, e.start_ns * 1e-9,
                                         (e.start_ns + e.duration_ns) * 1e-9))
     tr.host.sort(key=lambda h: (h[1], -h[2]))
@@ -115,33 +118,54 @@ def top_modules(tr: Trace, lo: float, hi: float, n: int = 10) -> List[List]:
 
 
 class HostIndex:
-    """What the host was doing at a time `t`, from the `bench.` spans.
+    """What the host was doing at a time `t`, from the host spans.
 
-    Calls (`bench.call`) do not overlap one another, nor do the spans
-    inside and between them (seams, client work); `bench.window` encloses
-    everything and labels nothing."""
+    Spans nest (the program's `tent.kv.spray` holds the seam
+    `bench.transfer_sync`, which holds `tent.engine.transfer`, which holds
+    the engine's waves and drains), and a time is labelled by the innermost
+    span around it: the one that opened last. `bench.window` encloses
+    everything and `bench.call` one call; neither labels time. Inside a
+    call where no other span is open, the time is labelled by the span
+    that closed last in that call (`after:<span>`, `after:bench.call`
+    before the first); outside every call and span, `outside`."""
 
     def __init__(self, host: Sequence[Tuple[str, float, float]]):
-        self.calls = sorted((s, e) for n, s, e in host if n == "bench.call")
-        self.spans = sorted((s, e, n) for n, s, e in host
-                            if n not in ("bench.call", "bench.window"))
-        self._call_starts = [s for s, _ in self.calls]
-        self._span_starts = [s for s, _, _ in self.spans]
-        self._edges = sorted({t for s, e in self.calls for t in (s, e)}
-                             | {t for s, e, _ in self.spans for t in (s, e)})
+        edges = sorted({t for _, s, e in host for t in (s, e)})
+        # per elementary piece [edges[k], edges[k + 1]): its label
+        opens: Dict[float, List[Tuple[float, float, str]]] = defaultdict(list)
+        for n, s, e in host:
+            if e > s:
+                opens[s].append((s, -e, n))
+        active: List[Tuple[float, float, str]] = []  # (start, -end, name)
+        labels: List[str] = []
+        call: Tuple[float, float] = (float("inf"), float("-inf"))
+        last = ""  # the labelling span that closed last in the current call
+        for t in edges[:-1]:
+            closing = [a for a in active
+                       if -a[1] == t and a[2] not in FRAMES and a[0] >= call[0]]
+            if closing:
+                last = min(closing)[2]  # the outermost of those closing together
+            active = [a for a in active if -a[1] > t] + opens.get(t, [])
+            for s, ne, n in opens.get(t, []):
+                if n == "bench.call":
+                    call, last = (s, -ne), ""
+            inner = max((a for a in active if a[2] not in FRAMES), default=None)
+            if inner is not None:
+                labels.append(inner[2])
+            elif call[0] <= t < call[1]:
+                labels.append("after:" + (last or "bench.call"))
+            else:
+                labels.append("outside")
+        self._edges, self._labels = edges, labels
 
     def label(self, t: float) -> str:
-        """The span around `t`; inside a call but between seams, the seam
-        that ended last (`after:<seam>`); `outside` where nothing is."""
-        i = bisect.bisect_right(self._span_starts, t) - 1
-        if i >= 0 and self.spans[i][1] > t:
-            return self.spans[i][2]
-        j = bisect.bisect_right(self._call_starts, t) - 1
-        if j < 0 or self.calls[j][1] <= t:
+        """The innermost span around `t`; inside a call but in no other
+        span, `after:<the span that closed last>`; `outside` where
+        nothing is."""
+        k = bisect.bisect_right(self._edges, t) - 1
+        if k < 0 or k >= len(self._labels):
             return "outside"
-        if i >= 0 and self.spans[i][0] >= self.calls[j][0]:
-            return "after:" + self.spans[i][2]
-        return "after:bench.call"
+        return self._labels[k]
 
     def split(self, lo: float, hi: float) -> List[Tuple[str, float]]:
         """[lo, hi] cut at every span's edge, each piece labelled."""

@@ -112,3 +112,55 @@ def test_recorded_v5e_trace():
     # the device waits on the host's copies and the spray
     idle = dict(map(tuple, gaps))
     assert idle["bench.tree_to_bytes"] > 0.01 and idle["bench.transfer_sync"] > 0.01
+
+
+def nested_trace():
+    """One call with the program's spans around and inside the harness's
+    seams, as a traced run records them: the spray holds the seam, which
+    holds the engine's transfer, its drain and a wave inside that."""
+    t = tr.Trace()
+    t.ops["/device:TPU:0"] = [(0.0, 0.05)]
+    t.host = sorted([
+        ("bench.window", 0.0, 10.0), ("bench.call", 1.0, 9.0), ("tent.generate", 1.1, 8.8),
+        ("tent.prefill", 1.2, 2.0), ("bench.prefill_jit", 1.3, 1.9),
+        ("tent.kv.pack", 2.0, 3.0), ("bench.tree_to_bytes", 2.1, 2.9),
+        ("tent.kv.segments", 3.0, 4.0),
+        ("tent.kv.spray", 4.0, 6.0), ("bench.transfer_sync", 4.1, 5.9),
+        ("tent.engine.transfer", 4.2, 5.8), ("tent.engine.drain", 4.5, 5.5),
+        ("tent.engine.wave", 4.9, 5.1),
+        ("tent.kv.read", 6.0, 7.0),
+        ("tent.decode", 7.0, 8.5), ("tent.decode.step", 7.1, 7.2),
+        ("bench.decode_step_jit", 7.12, 7.18), ("tent.decode.fetch", 7.2, 7.4),
+        ("bench.client", 9.2, 9.5)], key=lambda h: (h[1], -h[2]))
+    return t
+
+
+@pytest.mark.parametrize("t,label", [
+    (0.5, "outside"), (1.05, "after:bench.call"), (1.15, "tent.generate"),
+    (1.25, "tent.prefill"), (1.5, "bench.prefill_jit"), (2.05, "tent.kv.pack"),
+    (2.5, "bench.tree_to_bytes"), (3.5, "tent.kv.segments"), (4.05, "tent.kv.spray"),
+    (4.15, "bench.transfer_sync"), (4.3, "tent.engine.transfer"),
+    (4.6, "tent.engine.drain"), (5.0, "tent.engine.wave"), (5.3, "tent.engine.drain"),
+    (5.7, "tent.engine.transfer"), (5.85, "bench.transfer_sync"), (6.5, "tent.kv.read"),
+    (7.15, "bench.decode_step_jit"), (7.19, "tent.decode.step"),
+    (7.3, "tent.decode.fetch"), (7.5, "tent.decode"), (8.6, "tent.generate"),
+    (8.9, "after:tent.generate"), (9.1, "outside"), (9.3, "bench.client"),
+    (9.8, "outside")])
+def test_nested_spans_label_by_the_innermost(t, label):
+    assert tr.HostIndex(nested_trace().host).label(t) == label
+
+
+def test_after_labels_only_time_no_span_covers():
+    t = nested_trace()
+    gaps = dict(map(tuple, tr.idle_by_host(t, 0.0, 10.0, n=50)))
+    assert sum(gaps.values()) == pytest.approx(10.0 - 0.05)
+    after = {k: v for k, v in gaps.items() if k.startswith("after:")}
+    # the call's first 0.1 s, before tent.generate, and its last 0.2 s
+    assert after == {"after:bench.call": pytest.approx(0.1),
+                     "after:tent.generate": pytest.approx(0.2)}
+    assert gaps["tent.kv.segments"] == pytest.approx(1.0)
+    assert gaps["tent.kv.read"] == pytest.approx(1.0)
+    assert gaps["tent.engine.drain"] == pytest.approx(0.8)  # less its wave
+    assert gaps["tent.engine.wave"] == pytest.approx(0.2)
+    assert gaps["bench.transfer_sync"] == pytest.approx(0.2)
+    assert gaps["tent.generate"] == pytest.approx(0.1 + 0.3)
