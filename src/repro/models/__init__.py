@@ -1,5 +1,6 @@
 from .model import (
     abstract_params,
+    cache_filled_bytes,
     cache_shapes,
     decode_step,
     encode,
@@ -15,7 +16,7 @@ from .model import (
 )
 
 __all__ = [
-    "abstract_params", "cache_shapes", "decode_step", "encode", "forward",
-    "init_cache", "init_params", "loss_fn", "param_shapes", "prefill",
+    "abstract_params", "cache_filled_bytes", "cache_shapes", "decode_step", "encode",
+    "forward", "init_cache", "init_params", "loss_fn", "param_shapes", "prefill",
     "prefill_forward", "prefill_path", "prefill_replay",
 ]

@@ -16,6 +16,7 @@ size depth-independent (88- and 94-layer configs compile quickly even on a
   forward                         causal LM forward (train & prefill)
   loss_fn                         token CE + MoE aux losses
   init_cache / cache_shapes       decode caches (concrete / abstract)
+  cache_filled_bytes              the bytes of a cache that a prefill wrote
   decode_step                     single-token serve step
   prefill                         serving prefill: last logits + decode cache
                                   (one parallel causal pass where
@@ -30,6 +31,7 @@ size depth-independent (88- and 94-layer configs compile quickly even on a
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -417,6 +419,23 @@ def _cache_struct(
         s["enc_k"] = ((L, batch, enc_len, K, Hd), dtype)
         s["enc_v"] = ((L, batch, enc_len, K, Hd), dtype)
     return s
+
+
+def cache_filled_bytes(cache: Dict[str, Any], prompt_len: int) -> int:
+    """Bytes of a cache in `_cache_struct`'s layout that a prefill of
+    `prompt_len` tokens wrote, from the leaves' shapes and dtypes alone: the
+    self-attention K/V (L, B, W, K, Hd) count min(prompt_len, W) of their W
+    positions (a sliding-window ring fills whole once the prompt passes
+    it); `ssm_state`, `conv_buf` and the encoder's `enc_k`/`enc_v` hold no
+    position axis and count whole."""
+    total = 0
+    for name, leaf in cache.items():
+        nbytes = math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
+        if name in ("k", "v"):
+            W = leaf.shape[2]
+            nbytes = nbytes // W * min(prompt_len, W)
+        total += nbytes
+    return total
 
 
 def cache_shapes(

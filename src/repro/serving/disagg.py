@@ -19,7 +19,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..core import Location, MemoryKind, TentEngine
-from ..models import decode_step, prefill, prefill_path
+from ..models import cache_filled_bytes, decode_step, prefill, prefill_path
 from ..obs.spans import span
 
 # Compiled once per (config, shapes); the weights are arguments, never
@@ -116,8 +116,11 @@ class DisaggregatedServer:
           served the call): the dispatch of the prefill program.
           Asynchronous: the device may still run it when the span closes,
           and the wait then lands in `tent.kv.pack`.
-        - `tent.kv.pack`: `tree_to_bytes`, the device-to-host copy of the
-          cache.
+        - `tent.kv.pack` (attrs `bytes`, the size of `tree_to_bytes`'s
+          output, which is `DisaggResult.kv_bytes`, and `filled_bytes`,
+          the part of those bytes that the prefill wrote, from the
+          cache's shapes by `cache_filled_bytes`): `tree_to_bytes`, the
+          device-to-host copy of the cache, all `max_len` positions of it.
         - `tent.kv.segments`: both segments registered and the bytes
           written into the source (with `async_handoff`, all of
           `ship_kv_async`, which also posts the batch's first wave).
@@ -137,8 +140,11 @@ class DisaggregatedServer:
                 last_logits, cache = prefill_jit(self.cfg, self.params, prompt, max_len,
                                                  enc_frames=enc_frames)
             # ---- ship the cache through TENT ----
-            with span(sp, "tent.kv.pack"):
+            with span(sp, "tent.kv.pack") as pack:
                 data, _ = tree_to_bytes(cache)
+                if pack is not None:
+                    pack["bytes"] = int(data.size)
+                    pack["filled_bytes"] = cache_filled_bytes(cache, prompt.shape[1])
             t0 = self.engine.fabric.now
             if async_handoff:
                 # intent mode: the batch is posted and the decode worker
