@@ -1,21 +1,12 @@
-# Serving-scale bench: batched SoA stepper vs per-request loop + fabric parity.
+# Serving-scale bench: batched SoA stepper vs per-request loop.
 """Production-stream serving scale benchmark.
 
-Two claims ride here, both produced by the PR that rebuilt the serving hot
-path as struct-of-arrays over requests and put a calendar queue under the
-fabric's event loop:
-
-  * **requests-simulated/sec** — the batched stepper (whole phases advance
-    per virtual-clock tick over the `RequestTable`, cold-prefix promotions
-    leave as one cohort batch per tick) against the per-request event-driven
-    closed loop (every request a chain of fabric callbacks, every promotion
-    its own batch). Both run the same slowed production fabric; the floor is
-    ``SCALE_SPEEDUP_FLOOR``x.
-  * **fabric event-queue parity** — the `serving_production_stream` scenario
-    run on the binary-heap fabric and on the calendar-queue fabric must
-    produce byte-identical `ScenarioReport`s (the spec echo of the toggle
-    itself is the only permitted difference). The calendar queue is a pure
-    cost change, exactly like wave/wave_complete/jit_core before it.
+One claim rides here: **requests-simulated/sec** of the batched stepper
+(whole phases advance per virtual-clock tick over the `RequestTable`,
+cold-prefix promotions leave as one cohort batch per tick) against the
+per-request event-driven closed loop (every request a chain of fabric
+callbacks, every promotion its own batch). Both run the same slowed
+production fabric; the floor is ``SCALE_SPEEDUP_FLOOR``x.
 
 All simulated times are virtual; the requests/sec rates are wall-clock and
 machine-dependent, which is why the gate is a wide floor and not a pin.
@@ -106,30 +97,6 @@ def bench_async(quick: bool) -> dict:
     }
 
 
-def check_fabric_parity(quick: bool) -> dict:
-    """Heap vs calendar event queue over the full scenario (all policies):
-    the reports must be byte-identical once the toggle's own spec echo is
-    normalized out."""
-    spec = _stream_spec(quick)
-    if quick:
-        # parity is scale-invariant (same event order at any size); the
-        # quick arm shrinks further so CI pays seconds, not a minute
-        spec = dataclasses.replace(
-            spec,
-            workload=dataclasses.replace(spec.workload, stream_requests=5_000))
-
-    def normalized(s) -> str:
-        d = ScenarioRunner(s).run().to_dict()
-        d["spec"]["engine"]["calendar_queue"] = None
-        return json.dumps(d, sort_keys=True)
-
-    heap_doc = normalized(spec)
-    cal_doc = normalized(dataclasses.replace(
-        spec, engine=dataclasses.replace(spec.engine, calendar_queue=True)))
-    return {"identical": heap_doc == cal_doc,
-            "requests": spec.workload.stream_requests}
-
-
 def _policy_report(rate: float, extra: dict) -> dict:
     """Minimal PolicyReport-shaped dict (the keys benchmarks.diff consumes)
     with the requests-simulated/sec rate in the throughput slot."""
@@ -166,25 +133,6 @@ def run(quick: bool = False) -> list:
         "spec": {"policies": ["batched", "per_request"],
                  "scenario": SCENARIO, "quick": quick},
     }]
-
-    parity = check_fabric_parity(quick)
-    parity_violations = []
-    if not parity["identical"]:
-        parity_violations.append(
-            "calendar-queue fabric produced a different ScenarioReport than "
-            "the binary heap (bit-parity broken)")
-    docs.append({
-        "scenario": "serving_stream_fabric_parity",
-        "ok": not parity_violations,
-        "violations": parity_violations,
-        "policies": {
-            "calendar_vs_heap": _policy_report(
-                1.0 if parity["identical"] else 0.0,
-                {"mode": "calendar_vs_heap", **parity}),
-        },
-        "spec": {"policies": ["calendar_vs_heap"], "scenario": SCENARIO,
-                 "quick": quick},
-    })
     return docs
 
 
@@ -198,10 +146,6 @@ def render(docs: list) -> None:
           f"{p['wall_seconds']:6.1f}s wall = {p['rate']:>10,.0f} req/s")
     print(f"  speedup: {b['speedup_vs_per_request']:.1f}x "
           f"(floor {SCALE_SPEEDUP_FLOOR:.0f}x)")
-    par = docs[1]["policies"]["calendar_vs_heap"]["extra"]
-    print(f"\nserving_stream_fabric_parity")
-    print(f"  heap vs calendar over {par['requests']} requests: "
-          f"{'byte-identical' if par['identical'] else 'MISMATCH'}")
     for doc in docs:
         for v in doc["violations"]:
             print(f"  VIOLATION: {v}", file=sys.stderr)

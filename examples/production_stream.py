@@ -3,14 +3,12 @@
 Builds the request mix with `repro.scenarios.traffic` — the same seeded
 generator the `serving_production_stream` scenario, `benchmarks/serving_scale.py`,
 and the Monte-Carlo sweep lowering share — inspects its shape, then runs a
-scaled-down production stream through the batched SoA stepper on both the
-binary-heap and the calendar-queue fabric event loop and shows the reports
-are byte-identical (the toggle is a pure cost change).
+scaled-down production stream through the batched SoA stepper and prints its
+report.
 
 Run:  PYTHONPATH=src python examples/production_stream.py
 """
 import dataclasses
-import json
 import time
 
 import numpy as np
@@ -37,28 +35,16 @@ print(f"residency model: {cold} cold prefixes promote "
       f"{promo.sum()/1e9:.1f} GB store->GPU; "
       f"{len(traffic) - cold} re-hit GPU-resident KV for free\n")
 
-# --- the same stream through the batched stepper, both event queues --------
+# --- the same stream through the batched stepper --------------------------
 small = dataclasses.replace(
     spec, workload=dataclasses.replace(wl, stream_requests=20_000))
-reports = {}
-for calendar in (False, True):
-    s = dataclasses.replace(
-        small, engine=dataclasses.replace(small.engine,
-                                          calendar_queue=calendar))
-    t0 = time.time()
-    rep = ScenarioRunner(s).run()
-    wall = time.time() - t0
-    tent = rep.policies["tent"]
-    label = "calendar" if calendar else "heap"
-    print(f"[{label:8s}] {20_000/wall:7.0f} requests-simulated/s | "
-          f"tent {tent.throughput:7.0f} tok/s, "
-          f"TTFT P90 {tent.extra['p90_ttft_s']:.2f}s, "
-          f"TPOT P99 {tent.extra['p99_tpot_s']*1e3:.1f}ms | "
-          f"ok={rep.ok}")
-    d = rep.to_dict()
-    d["spec"]["engine"]["calendar_queue"] = None  # the toggle's own echo
-    reports[label] = json.dumps(d, sort_keys=True)
-
-assert reports["heap"] == reports["calendar"]
-print("\nheap vs calendar ScenarioReports: byte-identical "
-      "(same pops, same RNG draws, same simulation)")
+t0 = time.time()
+rep = ScenarioRunner(small).run()
+wall = time.time() - t0
+print(f"{20_000/wall:7.0f} requests-simulated/s, ok={rep.ok}")
+for name, pol in rep.policies.items():
+    print(f"  {name:12s} {pol.throughput:7.0f} tok/s, "
+          f"TTFT P90 {pol.extra['p90_ttft_s']:.2f}s, "
+          f"TPOT P99 {pol.extra['p99_tpot_s']*1e3:.1f}ms")
+for v in rep.violations:
+    print(f"  VIOLATION: {v}")

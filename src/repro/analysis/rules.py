@@ -291,7 +291,7 @@ class FmaHazard(Rule):
 
 class UnorderedIteration(Rule):
     """Ordering-stable iteration (the byte-identical `ScenarioReport` pins
-    across the wave/jit/calendar toggles).
+    across the wave and drain toggles, and the library's golden pins).
 
     Python `set` iteration order depends on element hashes — for strings,
     on PYTHONHASHSEED — so a set iterated into scheduling or report

@@ -43,7 +43,7 @@ from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 from ..core.engine import EngineConfig, TentEngine
 from ..obs import events as OBS
-from ..core.fabric import Fabric, FabricConfig
+from ..core.fabric import Fabric
 from ..core.topology import FabricSpec, Topology
 from .diffusion import GlobalLoadTable
 from .gossip import GossipChannel, PeerSampler
@@ -114,10 +114,7 @@ class TentCluster:
         self.params = params or ClusterParams()
         self.topology = Topology(spec)
         self._base_config = engine_config or EngineConfig()
-        self.fabric = Fabric(
-            self.topology, seed=seed,
-            config=FabricConfig(event_queue="calendar")
-            if self._base_config.calendar_queue else None)
+        self.fabric = Fabric(self.topology, seed=seed)
         self.seed = seed
         self.roles = tuple(roles)
         self._validate_roles(self.roles, spec.n_nodes)

@@ -2,10 +2,8 @@
 primary contribution), plus the discrete-event fabric it executes on in this
 reproduction."""
 from .engine import BatchResult, EngineConfig, TentEngine
-from .calqueue import CalendarQueue
-from .fabric import FAR_WINDOW, Fabric, FabricConfig
+from .fabric import FAR_WINDOW, Fabric
 from .jit_core import (
-    EngineJitCore,
     SprayProgram,
     jax_available,
     make_draws,
@@ -52,9 +50,8 @@ from .types import (
 )
 
 __all__ = [
-    "BatchResult", "CalendarQueue", "EngineConfig", "TentEngine", "FAR_WINDOW",
-    "Fabric", "FabricConfig",
-    "EngineJitCore", "SprayProgram", "jax_available", "make_draws",
+    "BatchResult", "EngineConfig", "TentEngine", "FAR_WINDOW", "Fabric",
+    "SprayProgram", "jax_available", "make_draws",
     "simulate_spray_ref", "spray_single", "spray_sweep", "Orchestrator",
     "RouteOption", "Stage", "StageCandidates", "TransportPlan",
     "build_stage_candidates", "HealthConfig", "HealthMonitor",

@@ -17,7 +17,7 @@ import numpy as np
 
 from ..obs import events as OBS
 from ..analysis import hot_path
-from .fabric import Fabric, FabricConfig
+from .fabric import Fabric
 from .plan import Orchestrator, Stage, StageCandidates, TransportPlan, build_stage_candidates
 from .resilience import HealthConfig, HealthMonitor
 from .scheduler import Policy, TentPolicy, make_policy
@@ -90,24 +90,6 @@ class EngineConfig:
     # and completion-batch sizes.
     wave_complete: bool = True
     wave_min: Optional[int] = None
-    # `jit_core` routes the two array kernels of the closed loop — the wave
-    # chooser and the batched completion drain — through jitted fixed-shape
-    # lax.scan kernels (repro.core.jit_core), padded to power-of-two shape
-    # buckets and run under x64 so results stay bit-identical to the numpy
-    # path (pinned in tests/test_jit_parity.py). The scalar/wave Python path
-    # remains the fallback for small batches (own online-tuned crossover,
-    # mirroring `wave_min`), staged hops, retries, and app callbacks; engines
-    # with a FlightRecorder attached fall back entirely (see
-    # `attach_recorder`). Off by default: jax dispatch only pays off on fat
-    # waves, and the default path must not require jax at import.
-    jit_core: bool = False
-    # Run the fabric event loop on the calendar queue (bucketed timestamp
-    # wheel, `repro.core.calqueue`) instead of the binary heap. Bit-identical
-    # pop order (pinned across the library in tests/test_calendar_parity.py);
-    # O(1) amortized per event, which pays off at production-scale serving
-    # streams (10^5+ in-flight events). Only consulted when the engine builds
-    # its own fabric — a fabric passed in keeps its own FabricConfig.
-    calendar_queue: bool = False
 
 
 @dataclasses.dataclass
@@ -197,10 +179,7 @@ class TentEngine:
         self.topology = topology
         self.config = config or EngineConfig()
         if fabric is None:
-            fabric = Fabric(
-                topology, seed=seed,
-                config=FabricConfig(event_queue="calendar")
-                if self.config.calendar_queue else None)
+            fabric = Fabric(topology, seed=seed)
         self.fabric = fabric
         self.segments = segments or SegmentManager()
         self.backends = load_backends(topology)
@@ -232,20 +211,6 @@ class TentEngine:
             WAVE_MIN if self._adaptive_wave_min else max(1, self.config.wave_min))
         self._run_ewma = 0.0
         self._drain_ewma = 0.0
-        # jitted-core adapter (EngineConfig.jit_core): None = scalar/numpy
-        # path everywhere. Requires the wave-capable TentPolicy — baseline
-        # ablation policies have no vectorized chooser to fuse.
-        self._jit = None
-        if self.config.jit_core and self._wave_policy:
-            from . import jit_core as _jc
-            if _jc.jax_available():
-                self._jit = _jc.EngineJitCore(self.policy, self.store)
-            else:
-                import warnings
-                warnings.warn(
-                    "EngineConfig.jit_core requested but jax is unavailable; "
-                    "falling back to the numpy wave path",
-                    RuntimeWarning, stacklevel=3)
         # armed only inside the batched failure drain: scalar `_issue` calls
         # append their post specs here instead of posting, and the drain
         # flushes them through one `post_many` (stream-identical to the
@@ -293,19 +258,6 @@ class TentEngine:
         self._rec = rec
         self.fabric.attach_recorder(rec)
         self.health.attach_recorder(rec, self.fabric, owner=self.name)
-        if self._jit is not None:
-            # Recorder appends (wave provenance snapshots, drain payloads)
-            # must be statically absent inside jitted kernels — tracing them
-            # would silently capture stale traced arrays. Tracing therefore
-            # forces the scalar/numpy path, loudly; reports stay identical
-            # because both paths are bit-exact (tests/test_obs.py pins this).
-            import warnings
-            warnings.warn(
-                f"engine {self.name!r}: FlightRecorder attached with "
-                "jit_core enabled; disabling the jitted core for this "
-                "engine (record sites cannot run under jit)",
-                RuntimeWarning, stacklevel=2)
-            self._jit = None
 
     def attach_spans(self, rec) -> None:
         """Attach a `repro.obs.spans.HostSpans` (None detaches). Each
@@ -317,8 +269,7 @@ class TentEngine:
         and every batched drain a `tent.engine.drain` (attrs `slices`,
         `bytes`), where the slices' bytes are copied. The scalar paths
         record nothing (that would be a span per slice): their time is the
-        transfer's own. Spans run outside jitted code, so unlike the flight
-        recorder they leave the jitted core on."""
+        transfer's own."""
         self._spans = rec
 
     def register_metrics(self, reg) -> None:
@@ -586,11 +537,7 @@ class TentEngine:
                 # the line-11 charges mutate the queue array (one dict of
                 # fresh arrays per wave, nothing per slice)
                 prov = self.policy.wave_inputs(sc) if rec is not None else None
-                jit = self._jit
-                if jit is not None and len(run) >= jit.min_batch:
-                    choices, queued_at = jit.choose_wave(sc, lengths)
-                else:
-                    choices, queued_at = self.policy.choose_wave(sc, lengths)
+                choices, queued_at = self.policy.choose_wave(sc, lengths)
                 if rec is not None:
                     # slice refs, not ids: interning is deferred to the
                     # recorder's first read so the timed path stays O(1)
@@ -641,9 +588,6 @@ class TentEngine:
             self._wave_min = WAVE_MIN_CEIL
         else:
             self._wave_min = WAVE_MIN
-        if self._jit is not None:
-            # same structural signal drives the numpy/jit crossover
-            self._jit.tune(signal)
 
     @property
     def wave_min(self) -> int:
@@ -1004,11 +948,7 @@ class TentEngine:
         lengths = np.asarray(len_c, dtype=np.int64)
         queued_at = np.asarray(queued_c, dtype=np.int64)
         t_obs = now - np.asarray(sched_c, dtype=np.float64)
-        jit = self._jit
-        if jit is not None and len(slots) >= jit.min_batch:
-            jit.on_complete_many(slots, lengths, queued_at, t_obs)
-        else:
-            store.on_complete_many(slots, lengths, queued_at, t_obs)
+        store.on_complete_many(slots, lengths, queued_at, t_obs)
         t_pred = np.asarray(pred_c, dtype=np.float64)
         if self.health.observe_many(slots, links_c, t_obs, t_pred):
             self._arm_probe_timer()

@@ -19,7 +19,6 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..obs import events as OBS
-from .calqueue import DEFAULT_WIDTH, CalendarQueue
 from .topology import LinkDesc, Topology
 
 # completion callback: (ok, start_time, end_time, error_code) — or, for
@@ -37,35 +36,6 @@ CompletionSink = Callable[[List["WireOp"], float], None]
 PostSpec = Tuple[int, Optional[int], int, float, float, object]
 
 _op_ids = itertools.count(1)
-
-
-@dataclasses.dataclass(frozen=True)
-class FabricConfig:
-    """Event-loop configuration, following the `wave`/`wave_complete`/
-    `jit_core` discipline: the default is the reference implementation, the
-    alternative is bit-identical and regression-pinned.
-
-    event_queue:
-        "heap"     — one flat binary heap (the reference; O(log n) per op).
-        "calendar" — bucketed timestamp wheel (`repro.core.calqueue`);
-                     O(1) amortized push/pop, same pop order byte for byte.
-                     Pays off once in-flight events reach 10^4-10^5+
-                     (production-scale serving streams); at small scale the
-                     constant factors roughly cancel.
-    calendar_width:
-        Initial bucket width in virtual seconds; 0 = library default. The
-        wheel self-resizes, so this is a hint, not a tuning obligation.
-    """
-
-    event_queue: str = "heap"
-    calendar_width: float = 0.0
-
-    def __post_init__(self):
-        if self.event_queue not in ("heap", "calendar"):
-            raise ValueError(f"unknown event_queue {self.event_queue!r}")
-        if self.calendar_width < 0:
-            raise ValueError(
-                f"calendar_width must be >= 0, got {self.calendar_width}")
 
 
 @dataclasses.dataclass(slots=True)
@@ -148,23 +118,14 @@ class Fabric:
 
     FAIL_DETECT_LATENCY = 200e-6  # completion-error surfacing delay (s)
 
-    def __init__(self, topology: Topology, *, seed: int = 0, jitter: float = 0.02,
-                 config: Optional["FabricConfig"] = None):
+    def __init__(self, topology: Topology, *, seed: int = 0, jitter: float = 0.02):
         self.topology = topology
-        self.config = config or FabricConfig()
         self.now = 0.0
         # queue entries are (time, seq, item); `item` is either a zero-arg
         # callable or a WireOp whose completion is due (op entries avoid a
         # per-op `partial` allocation and let `step` recognize and group
         # same-timestamp completion runs for the batched drain)
         self._events: List[Tuple[float, int, object]] = []
-        # calendar-queue alternative to the heap (FabricConfig.event_queue):
-        # same (time, seq) pop order, O(1) amortized at serving-stream scale.
-        # Exactly one of the two structures holds events; every loop site
-        # branches on `self._cal is None` so the heap path stays verbatim.
-        self._cal: Optional[CalendarQueue] = (
-            CalendarQueue(self.config.calendar_width or DEFAULT_WIDTH)
-            if self.config.event_queue == "calendar" else None)
         self._seq = itertools.count()
         self._rng = np.random.default_rng(seed)
         self._completion_sinks: Dict[object, CompletionSink] = {}
@@ -183,10 +144,7 @@ class Fabric:
     def call_at(self, t: float, fn: Callable[[], None]) -> None:
         if t < self.now:
             t = self.now
-        if self._cal is None:
-            heapq.heappush(self._events, (t, next(self._seq), fn))
-        else:
-            self._cal.push((t, next(self._seq), fn))
+        heapq.heappush(self._events, (t, next(self._seq), fn))
 
     def call_after(self, dt: float, fn: Callable[[], None]) -> None:
         self.call_at(self.now + dt, fn)
@@ -204,8 +162,6 @@ class Fabric:
         self._completion_sinks[on_complete] = sink
 
     def step(self) -> bool:
-        if self._cal is not None:
-            return self._step_calendar()
         events = self._events
         if not events:
             return False
@@ -227,34 +183,6 @@ class Fabric:
         fn()
         return True
 
-    def _step_calendar(self) -> bool:
-        """`step` on the calendar queue — same semantics, same batch grouping
-        of same-timestamp same-callback completion runs, via peek/pop instead
-        of heap indexing."""
-        cal = self._cal
-        if not cal:
-            return False
-        t, _, fn = cal.pop()
-        self.now = max(self.now, t)
-        if type(fn) is WireOp:
-            sink = (self._completion_sinks.get(fn.on_complete)
-                    if self._completion_sinks else None)
-            if sink is None:
-                self._complete(fn)
-                return True
-            batch = [fn]
-            cb = fn.on_complete
-            while cal:
-                head = cal.peek()
-                if head[0] != t or type(head[2]) is not WireOp \
-                        or head[2].on_complete != cb:
-                    break
-                batch.append(cal.pop()[2])
-            self._complete_batch(batch, sink)
-            return True
-        fn()
-        return True
-
     def run_until_idle(self, *, max_events: int = 50_000_000) -> None:
         n = 0
         while self.step():
@@ -263,19 +191,12 @@ class Fabric:
                 raise RuntimeError("fabric event budget exceeded (livelock?)")
 
     def run_until(self, t: float) -> None:
-        cal = self._cal
-        if cal is None:
-            while self._events and self._events[0][0] <= t:
-                self.step()
-        else:
-            while cal and cal.peek()[0] <= t:
-                self._step_calendar()
+        while self._events and self._events[0][0] <= t:
+            self.step()
         self.now = max(self.now, t)
 
     @property
     def idle(self) -> bool:
-        if self._cal is not None:
-            return not self._cal
         return not self._events
 
     # -- fault / degradation schedule -----------------------------------------
@@ -446,7 +367,6 @@ class Fabric:
         specs = list(specs)
         links = self.links
         events = self._events
-        cal = self._cal
         seq = self._seq
         now = self.now
         detect = self.FAIL_DETECT_LATENCY
@@ -487,11 +407,8 @@ class Fabric:
 
             if failed[src_link] or (dst is not None and failed[dst_link]):
                 op.failed = True
-                entry = (now + detect, next(seq), partial(self._deliver_reject, op))
-                if cal is None:
-                    heapq.heappush(events, entry)
-                else:
-                    cal.push(entry)
+                heapq.heappush(
+                    events, (now + detect, next(seq), partial(self._deliver_reject, op)))
                 continue
 
             start = max(now, src.busy_until, dst.busy_until if dst else 0.0)
@@ -511,10 +428,7 @@ class Fabric:
             src.outstanding[op.op_id] = op
             if dst is not None:
                 dst.outstanding[op.op_id] = op
-            if cal is None:
-                heapq.heappush(events, (max(end, now), next(seq), op))
-            else:
-                cal.push((max(end, now), next(seq), op))
+            heapq.heappush(events, (max(end, now), next(seq), op))
 
     def _complete(self, op: WireOp) -> None:
         if op.cancelled:

@@ -1,54 +1,31 @@
-"""Fused lax.scan simulation core (`EngineConfig.jit_core` + Monte Carlo).
+"""Fused lax.scan simulation core for Monte Carlo fault sweeps.
 
-Two layers share this module:
+`_build_sim` compiles a `SprayProgram` into a fully fused model of the spray
+closed loop: wave-choose -> busy-chain post -> fault check (+ one masked
+retry) -> completion-ordered EWMA drain, all inside one nested `lax.scan`
+over fixed-shape rail/slice arrays, with the fabric's deterministic fault
+schedule compiled into per-rail window arrays (`Fabric.fault_window_arrays`)
+and per-seed jitters applied to fault onset/duration/depth. `vmap` over seed
+keys yields whole healing-time/throughput distributions in one dispatch
+(`spray_sweep`); `simulate_spray_ref` is the op-for-op numpy twin the
+property tests pin the jax path against, bit-exact at float64.
 
-1. `EngineJitCore` — the engine-side adapter behind `EngineConfig.jit_core`.
-   It routes the two telemetry-array kernels of the closed loop — the wave
-   chooser (`TentPolicy.choose_wave`) and the batched completion drain
-   (`TelemetryStore.on_complete_many`) — through jitted, shape-bucketed
-   `lax.scan` kernels (`tent_choose_wave_padded_jnp`,
-   `tent_on_complete_many_jnp`). Arrays are padded to power-of-two buckets
-   so one compiled kernel serves every wave/drain of a scenario, and all
-   kernels run under `x64()` (`jax.enable_x64(True)`), so results are
-   bit-identical to the numpy path (pinned in tests/test_jit_parity.py).
-   The scalar/wave Python path stays in charge of everything stateful —
-   staged hops, retries, substitutions, app callbacks — exactly as before;
-   the adapter only replaces the arithmetic inside two already-batched
-   call sites, selected per-batch by an online-tuned crossover that mirrors
-   the `WAVE_MIN` tuner.
-
-2. `SprayProgram` / `simulate_spray` — a fully fused model of the spray
-   closed loop for Monte Carlo fault sweeps: wave-choose -> busy-chain
-   post -> fault check (+ one masked retry) -> completion-ordered EWMA
-   drain, all inside one nested `lax.scan` over fixed-shape rail/slice
-   arrays, with the fabric's deterministic fault schedule compiled into
-   per-rail window arrays (`Fabric.fault_window_arrays`) and per-seed
-   jitters applied to fault onset/duration/depth. `vmap` over seed keys
-   yields whole healing-time/throughput distributions in one dispatch
-   (`spray_sweep`); `simulate_spray_ref` is the op-for-op numpy twin the
-   property tests pin the jax path against, bit-exact at float64.
-
-The model the MC layer runs is deliberately the *skeleton* of the engine,
-not the engine: one plan stage, uniform slice length, one retry attempt,
-round-granular clock advancement. Scenarios that need staged hops, backend
-substitution chains, or app callbacks keep the full event-driven
-`ScenarioRunner` path — the same scalar-fallback contract the engine-side
-adapter follows.
+The model is deliberately the *skeleton* of the engine, not the engine: one
+plan stage, uniform slice length, one retry attempt, round-granular clock
+advancement. Scenarios that need staged hops, backend substitution chains,
+or app callbacks keep the full event-driven `ScenarioRunner` path.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import numpy as np
 
 from .fabric import FAR_WINDOW
-from ..analysis import hot_path
-from .scheduler import tent_choose_wave_padded_jnp, tent_on_complete_many_jnp
 
 __all__ = [
-    "EngineJitCore",
     "SprayProgram",
     "jax_available",
     "make_draws",
@@ -56,9 +33,6 @@ __all__ = [
     "spray_single",
     "spray_sweep",
     "x64",
-    "JIT_MIN",
-    "JIT_MIN_FLOOR",
-    "JIT_MIN_CEIL",
 ]
 
 
@@ -77,151 +51,6 @@ def x64():
     import jax
 
     return jax.enable_x64(True)
-
-
-def _bucket(n: int, floor: int = 8) -> int:
-    """Next power-of-two shape bucket (>= floor): bounds the number of
-    distinct compiled kernel shapes per scenario to O(log max_batch)."""
-    return max(floor, 1 << (max(int(n), 1) - 1).bit_length())
-
-
-# ---------------------------------------------------------------------------
-# Engine-side adapter (`EngineConfig.jit_core`)
-# ---------------------------------------------------------------------------
-
-# Batches shorter than this stay on the numpy kernels: a jax dispatch costs
-# ~10-50x a small numpy gather, so the jitted path only pays off on fat
-# waves/drains (elephant scenarios routinely run 64-256). Mirroring the
-# WAVE_MIN tuner, the crossover adapts online from the same run-length /
-# drain-size EWMAs unless traffic is inconclusive — and because both paths
-# compute bit-identical results, the tuner can only ever change cost, never
-# a scheduling decision.
-JIT_MIN = 32
-JIT_MIN_FLOOR = 16
-JIT_MIN_CEIL = 64
-
-_ENGINE_KERNELS: Optional[dict] = None
-
-
-def _engine_kernels() -> dict:
-    global _ENGINE_KERNELS
-    if _ENGINE_KERNELS is None:
-        import jax
-
-        _ENGINE_KERNELS = {
-            "choose": jax.jit(tent_choose_wave_padded_jnp),
-            "drain": jax.jit(tent_on_complete_many_jnp),
-        }
-    return _ENGINE_KERNELS
-
-
-class EngineJitCore:
-    """Routes `choose_wave` / `on_complete_many` through jitted fixed-shape
-    kernels, bit-identically to the numpy path. Stateless beyond counters
-    and the tuned crossover: all telemetry state stays in the store's
-    struct-of-arrays, gathered/scattered per call through the telemetry
-    transport hooks, so the scalar path can take over at any batch."""
-
-    def __init__(self, policy, store):
-        self.policy = policy
-        self.store = store
-        self.min_batch = JIT_MIN
-        self.waves = 0  # batches actually dispatched through the jitted chooser
-        self.drains = 0  # batches actually dispatched through the jitted drain
-
-    def tune(self, signal: float) -> None:
-        """Online crossover tuning, same shape as `TentEngine._tune_wave_min`
-        and driven by the same structural signal (run-length / drain-size
-        EWMAs — never wall clock, so it stays deterministic)."""
-        if signal >= 2.0 * JIT_MIN:
-            self.min_batch = JIT_MIN_FLOOR
-        elif signal <= 0.5 * JIT_MIN:
-            self.min_batch = JIT_MIN_CEIL
-        else:
-            self.min_batch = JIT_MIN
-
-    # -- wave chooser --------------------------------------------------------
-    @hot_path
-    def choose_wave(self, sc, lengths):
-        """Jitted twin of `TentPolicy.choose_wave`: same gathers, same
-        write-backs, padded to shape buckets. Returns int64
-        `(choices, queued_at)` exactly like the numpy kernel."""
-        policy, store = self.policy, self.store
-        slots = sc.local_slot
-        excluded = store.excluded_arr[slots]
-        if sc.remote_any:
-            excluded = excluded | (
-                sc.has_remote & store.excluded_arr[sc.remote_slot_safe])
-        if store.global_weight > 0.0:
-            glocal = store.foreign_load_array(sc.local_links)
-            gremote = store.foreign_load_array(sc.remote_links)
-        else:
-            glocal = gremote = sc.zeros
-        n_c, n_s = len(slots), len(lengths)
-        pc, ps = _bucket(n_c), _bucket(n_s)
-        # candidate axis: pads score inf in both the masked and the
-        # all-excluded-fallback pass (penalty inf + excluded)
-        q = np.zeros(pc, dtype=np.float64)
-        q[:n_c] = store.queued_arr[slots]
-        gl = np.zeros(pc, dtype=np.float64)
-        gl[:n_c] = glocal
-        gr = np.zeros(pc, dtype=np.float64)
-        gr[:n_c] = gremote
-        bw = np.ones(pc, dtype=np.float64)
-        bw[:n_c] = sc.bandwidth
-        b0 = np.zeros(pc, dtype=np.float64)
-        b0[:n_c] = store.beta0_arr[slots]
-        b1 = np.ones(pc, dtype=np.float64)
-        b1[:n_c] = store.beta1_arr[slots]
-        pen = np.full(pc, np.inf, dtype=np.float64)
-        pen[:n_c] = sc.penalty
-        ex = np.ones(pc, dtype=bool)
-        ex[:n_c] = excluded
-        ln = np.zeros(ps, dtype=np.float64)
-        ln[:n_s] = lengths
-        valid = np.zeros(ps, dtype=bool)
-        valid[:n_s] = True
-        kern = _engine_kernels()["choose"]
-        with x64():
-            c_j, qa_j, qo_j, rr_j = kern(
-                q, gl, gr, bw, b0, b1, pen, ex, ln, valid,
-                policy._rr, policy.gamma)
-            choices = np.asarray(c_j)[:n_s].astype(np.int64)
-            queued_at = np.asarray(qa_j)[:n_s].astype(np.int64)
-            queued_out = np.asarray(qo_j)[:n_c].astype(np.int64)
-            rr = int(rr_j)
-        store.queued_arr[slots] = queued_out  # line 11 charges, applied
-        policy._rr = rr
-        self.waves += 1
-        return choices, queued_at
-
-    # -- completion drain ----------------------------------------------------
-    @hot_path
-    def on_complete_many(self, slots, lengths, queued_at, t_obs) -> None:
-        """Jitted twin of `TelemetryStore.on_complete_many`: full state
-        vectors travel through the telemetry transport hooks; batch padding
-        scatters into the store's scratch row (slot `n`), which the
-        write-back discards."""
-        store = self.store
-        n = store.n
-        ps = _bucket(n + 1)  # >= n+1: row n is the scratch slot
-        m = len(slots)
-        pm = _bucket(m)
-        state = store.gather_complete_state(ps)
-        sl = np.full(pm, n, dtype=np.int64)
-        sl[:m] = slots
-        ln = np.zeros(pm, dtype=np.float64)
-        ln[:m] = lengths
-        qa = np.zeros(pm, dtype=np.float64)
-        qa[:m] = queued_at
-        to = np.zeros(pm, dtype=np.float64)
-        to[:m] = t_obs
-        kern = _engine_kernels()["drain"]
-        with x64():
-            b0o, b1o, qo, ewo, co = kern(*state, sl, ln, qa, to)
-            out = tuple(np.asarray(a) for a in (b0o, b1o, qo, ewo, co))
-        store.scatter_complete_state(*out)
-        self.drains += 1
 
 
 # ---------------------------------------------------------------------------
@@ -677,8 +506,8 @@ def _build_sim(p: SprayProgram, policy: str, fault_jitter: float):
                 # `one` is a traced, always-1.0 divisor: dividing each EWMA
                 # product by it forces a separate IEEE rounding, blocking
                 # the backend's mul+add->fma contraction that would break
-                # bit-parity with simulate_spray_ref (same defense as
-                # tent_on_complete_many_jnp; exact, since x/1.0 == x).
+                # bit-parity with simulate_spray_ref (exact, since
+                # x/1.0 == x).
                 one = jnp.where(du >= 0, 1.0, 2.0)
                 tob = endt - now
                 a = alpha[du]

@@ -391,12 +391,6 @@ __numpy_twins__ = {
     "tent_choose_jnp": ["TentPolicy.choose",
                         "candidate-object API vs flat array inputs"],
     "tent_choose_wave_jnp": "tent_choose_wave",
-    "tent_on_complete_many_jnp": [
-        "TelemetryStore.on_complete_many",
-        "carries EWMA state as arrays; the twin reads the store's views"],
-    "tent_choose_wave_padded_jnp": [
-        "tent_choose_wave",
-        "padded fixed-shape variant adds the `valid` mask"],
 }
 
 
@@ -483,123 +477,4 @@ def tent_choose_wave_jnp(queued, global_local, global_remote, bandwidth,
 
     (q_out, rr_out), (choices, queued_at) = jax.lax.scan(
         step, (q0, jnp.asarray(rr, dtype=jnp.int32)), lengths)
-    return choices, queued_at, q_out, rr_out
-
-
-def tent_on_complete_many_jnp(beta0, beta1, queued, ewma_service, completions,
-                              ewma_alpha, beta0_alpha, bandwidth,
-                              slots, lengths, queued_at, t_obs):
-    """One-call JAX twin of `TelemetryStore.on_complete_many`: a `lax.scan`
-    over the completion batch applies the Eq. 1 EWMA feedback update one
-    completion at a time with `.at[slot]` scatters, so repeated slots within
-    a batch see exactly the sequential per-slot recurrence the scalar
-    `LinkTelemetry.on_complete` produces (parity is bit-exact under
-    `jit_core.x64()`, like the other kernels in this section).
-    Array arguments are full per-slot state vectors; `slots`/`lengths`/
-    `queued_at`/`t_obs` describe the batch in drain order. Returns the
-    updated `(beta0, beta1, queued, ewma_service, completions)` arrays."""
-    import jax
-    import jax.numpy as jnp
-
-    b0 = jnp.asarray(beta0, dtype=float)
-    b1 = jnp.asarray(beta1, dtype=float)
-    q = jnp.asarray(queued, dtype=float)
-    ew = jnp.asarray(ewma_service, dtype=float)
-    comp = jnp.asarray(completions)
-    alpha = jnp.asarray(ewma_alpha, dtype=float)
-    b0a = jnp.asarray(beta0_alpha, dtype=float)
-    bw = jnp.asarray(bandwidth, dtype=float)
-    batch = (jnp.asarray(slots, dtype=jnp.int32),
-             jnp.asarray(lengths, dtype=float),
-             jnp.asarray(queued_at, dtype=float),
-             jnp.asarray(t_obs, dtype=float))
-
-    def step(carry, inp):
-        b0_, b1_, q_, ew_, comp_ = carry
-        d, length, qas, tob = inp
-        # Every EWMA blend below is a `u*v + w*z` chain. Inside the
-        # compiled scan body, a multiply feeding an add/sub gets contracted
-        # into a single-rounded fma, breaking bit-parity with the scalar
-        # numpy recurrence by one ulp (optimization_barrier does NOT stop
-        # this — the backend contracts through it). Dividing each product
-        # by `one` — a traced value the compiler cannot fold, always
-        # exactly 1.0, and division by 1.0 is exact — forces a separate
-        # IEEE rounding per product: a division result feeding an add is
-        # not a contraction candidate.
-        one = jnp.where(d >= 0, 1.0, 2.0)
-        a = alpha[d]
-        x = (qas + length) / bw[d]
-        sample = jnp.clip(
-            (tob - b0_[d]) / jnp.where(x > 0, x, 1.0), 0.05, 1e4)
-        b1d = jnp.where(
-            x > 0,
-            ((1 - a) * b1_[d]) / one + (a * sample) / one,
-            b1_[d])
-        resid = jnp.maximum(0.0, tob - (b1d * x) / one)
-        b0d = ((1 - b0a[d]) * b0_[d]) / one + (b0a[d] * resid) / one
-        return (
-            b0_.at[d].set(b0d),
-            b1_.at[d].set(b1d),
-            q_.at[d].set(jnp.maximum(0.0, q_[d] - length)),
-            ew_.at[d].set(((1 - a) * ew_[d]) / one + (a * tob) / one),
-            comp_.at[d].add(1),
-        ), None
-
-    (b0, b1, q, ew, comp), _ = jax.lax.scan(step, (b0, b1, q, ew, comp), batch)
-    return b0, b1, q, ew, comp
-
-
-def tent_choose_wave_padded_jnp(queued, global_local, global_remote, bandwidth,
-                                beta0, beta1, penalty, excluded, lengths,
-                                valid, rr, gamma):
-    """Fixed-shape variant of `tent_choose_wave_jnp` for the jitted engine
-    core (`repro.core.jit_core`): both axes are padded up to a shape bucket
-    so one compiled kernel serves every wave of a scenario.
-
-    Padded *candidates* carry `penalty=inf` and `excluded=True`: they score
-    inf under the normal mask and inf again under the all-excluded fallback
-    (the raw cost model keeps the inf penalty), so they can never enter the
-    gamma window. Padded *slices* are masked by `valid`: they charge
-    nothing, leave the round-robin counter untouched, and emit
-    choice -1 / queued_at 0 — the caller slices them off. On the valid
-    prefix the outputs are bit-identical to the unpadded twin, and
-    therefore to the numpy `tent_choose_wave`, under `jit_core.x64()`."""
-    import jax
-    import jax.numpy as jnp
-
-    q0 = jnp.asarray(queued, dtype=float)
-    glocal = jnp.asarray(global_local, dtype=float)
-    gremote = jnp.asarray(global_remote, dtype=float)
-    bandwidth = jnp.asarray(bandwidth, dtype=float)
-    beta0 = jnp.asarray(beta0, dtype=float)
-    beta1 = jnp.asarray(beta1, dtype=float)
-    penalty = jnp.asarray(penalty, dtype=float)
-    ex = jnp.asarray(excluded, dtype=bool)
-    lengths = jnp.asarray(lengths, dtype=float)
-    valid = jnp.asarray(valid, dtype=bool)
-    arange = jnp.arange(q0.shape[0])
-
-    def step(carry, inp):
-        q, rr_ = carry
-        length, v = inp
-        q_eff = (q + glocal) + gremote
-        s = penalty * (beta0 + beta1 * (q_eff + length) / bandwidth)
-        s = jnp.where(ex, jnp.inf, s)
-        fallback = penalty * (beta0 + beta1 * (q + length) / bandwidth)
-        s = jnp.where(jnp.isinf(jnp.min(s)), fallback, s)
-        s_min = jnp.min(s)
-        ok = jnp.isfinite(s_min) & v
-        in_window = s <= (1.0 + gamma) * s_min
-        n_win = jnp.sum(in_window)
-        k = (rr_ % jnp.maximum(n_win, 1)).astype(jnp.int32)
-        order = jnp.cumsum(in_window.astype(jnp.int32)) - 1
-        match = jnp.where(in_window & (order == k), arange, s.shape[0])
-        chosen = jnp.min(match)
-        safe = jnp.where(ok, chosen, 0)
-        q = q.at[safe].add(jnp.where(ok, length, 0.0))
-        return (q, rr_ + ok.astype(rr_.dtype)), (
-            jnp.where(ok, chosen, -1), jnp.where(ok, q[safe], 0.0))
-
-    (q_out, rr_out), (choices, queued_at) = jax.lax.scan(
-        step, (q0, jnp.asarray(rr, dtype=jnp.int32)), (lengths, valid))
     return choices, queued_at, q_out, rr_out

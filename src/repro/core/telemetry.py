@@ -416,42 +416,6 @@ class TelemetryStore:
         self.ewma_service_arr[idx] = (
             (1 - alpha) * self.ewma_service_arr[idx] + alpha * t_obs)
 
-    # -- jitted-core state transport (repro.core.jit_core) -------------------
-    def gather_complete_state(self, pad_to: int):
-        """Padded float64 copies of everything `on_complete_many` reads or
-        writes, in the argument order of `tent_on_complete_many_jnp`:
-        `(beta0, beta1, queued, ewma_service, completions, ewma_alpha,
-        beta0_alpha, bandwidth)`. `pad_to` must be > `self.n`: rows past `n`
-        are inert scratch (alpha 0, bandwidth 1 — no NaNs, no visible
-        updates), and row `n` is the designated scratch slot batch padding
-        scatters into. Copies, never views — the kernel's write-back goes
-        through `scatter_complete_state`."""
-        n = self.n
-        out = []
-        for name, fill in (("beta0_arr", 0.0), ("beta1_arr", 1.0),
-                           ("queued_arr", 0.0), ("ewma_service_arr", 0.0),
-                           ("completions_arr", 0.0), ("ewma_alpha_arr", 0.0),
-                           ("beta0_alpha_arr", 0.0), ("bandwidth_arr", 1.0)):
-            arr = np.full(pad_to, fill, dtype=np.float64)
-            arr[:n] = getattr(self, name)[:n]
-            out.append(arr)
-        return tuple(out)
-
-    def scatter_complete_state(self, beta0, beta1, queued, ewma_service,
-                               completions) -> None:
-        """Write back the five state vectors `on_complete_many` mutates from
-        a jitted-kernel result (padded rows ignored). Queue depths and
-        completion counts travel as float64 but are exact — the engine's
-        byte counts stay far below 2**53 — so the int64 cast round-trips
-        bit-identically with the numpy path."""
-        n = self.n
-        self.beta0_arr[:n] = beta0[:n]
-        self.beta1_arr[:n] = beta1[:n]
-        self.queued_arr[:n] = np.asarray(queued[:n], dtype=np.float64).astype(np.int64)
-        self.ewma_service_arr[:n] = ewma_service[:n]
-        self.completions_arr[:n] = np.asarray(
-            completions[:n], dtype=np.float64).astype(np.int64)
-
     # -- bulk state ----------------------------------------------------------
     def reset_all(self) -> None:
         n = self.n

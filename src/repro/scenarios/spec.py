@@ -429,14 +429,6 @@ class EngineParams:
     candidate_cache: bool = True
     wave_complete: bool = True
     wave_min: Union[int, None] = None
-    # routes the wave chooser + completion drain through the jitted
-    # fixed-shape kernels (repro.core.jit_core); bit-identical to the numpy
-    # path, scalar fallback everywhere else (see EngineConfig.jit_core)
-    jit_core: bool = False
-    # runs the fabric event loop on the calendar queue (bucketed timestamp
-    # wheel) instead of the binary heap; bit-identical pop order, O(1)
-    # amortized at serving-stream scale (see EngineConfig.calendar_queue)
-    calendar_queue: bool = False
 
     def to_engine_config(self, policy: str) -> EngineConfig:
         return EngineConfig(
@@ -450,8 +442,6 @@ class EngineParams:
             candidate_cache=self.candidate_cache,
             wave_complete=self.wave_complete,
             wave_min=self.wave_min,
-            jit_core=self.jit_core,
-            calendar_queue=self.calendar_queue,
             health=HealthConfig(
                 probe_interval=self.probe_interval, retry_limit=self.retry_limit
             ),

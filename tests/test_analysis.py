@@ -260,15 +260,14 @@ class TestHotPathMarker:
         assert not is_hot_path(lambda: None)
 
     def test_known_hot_paths_are_tagged(self):
-        from repro.core.calqueue import CalendarQueue
         from repro.core.engine import TentEngine
         from repro.core.telemetry import TelemetryStore
+        from repro.serving.serve_sim import ServingSimulator
 
         assert is_hot_path(TentEngine._dispatch)
         assert is_hot_path(TentEngine._on_wire_done_many)
         assert is_hot_path(TelemetryStore.on_complete_many)
-        assert is_hot_path(CalendarQueue.push)
-        assert is_hot_path(CalendarQueue.pop)
+        assert is_hot_path(ServingSimulator._run_batched)
 
 
 # ---------------------------------------------------------------------------

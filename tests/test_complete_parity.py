@@ -56,10 +56,13 @@ class TestWaveCompleteBitIdentity:
             engine=dataclasses.replace(spec.engine, wave_complete=False)))
         assert on == off
 
-    def test_pinned_wave_min_keeps_reports_identical(self):
+    @pytest.mark.parametrize(
+        "name", ["single_rail_flap", "elephant_mice_mix",
+                 "degrade_recover_ramp"])
+    def test_pinned_wave_min_keeps_reports_identical(self, name):
         """The crossover is a pure cost knob: pinning it to either extreme
         must not move a single report metric."""
-        spec = get("single_rail_flap")
+        spec = get(name)
         base = _policies(spec)
         for pin in (1, WAVE_MIN_CEIL * 4):
             pinned = _policies(dataclasses.replace(

@@ -49,6 +49,7 @@ from repro.obs.explain import (
 )
 from repro.scenarios import SCENARIOS, ScenarioRunner, get
 from repro.scenarios.spec import ClusterWorkload
+from scenario_sizes import sized
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 ENGINE_COUNTER_KEYS = (
@@ -56,7 +57,7 @@ ENGINE_COUNTER_KEYS = (
 
 
 def _run_recorded(name, policy=None, capacity=1 << 18):
-    spec = get(name)
+    spec = sized(get(name))
     rec = FlightRecorder(capacity=capacity)
     rep = ScenarioRunner(spec).run_policy(
         policy or spec.policies[0], recorder=rec)
@@ -213,9 +214,12 @@ class TestReportParity:
             "tent+diffusion")
         assert rep_on.to_dict() == rep_off.to_dict()
 
-    def test_single_engine_report_parity(self):
-        rec, rep_on = _run_recorded("uniform_spray")
-        rep_off = ScenarioRunner(get("uniform_spray")).run_policy("tent")
+    @pytest.mark.parametrize("name", sorted(SCENARIOS))
+    def test_single_engine_report_parity(self, name):
+        """Every library scenario's primary policy, recorder on vs off."""
+        rec, rep_on = _run_recorded(name)
+        spec = sized(get(name))
+        rep_off = ScenarioRunner(spec).run_policy(spec.policies[0])
         assert rep_on.to_dict() == rep_off.to_dict()
         assert len(rec) > 0
 
@@ -410,41 +414,3 @@ class TestDocsDriftGuards:
         assert words.get(m.group(1)) == actual, (
             f"README says '{m.group(1)}' cluster entries, library has "
             f"{actual}")
-
-
-class TestRecorderVsJitCore:
-    """Record sites are statically absent from the jitted kernels
-    (`repro.core.jit_core` traces no recorder appends), so attaching a
-    FlightRecorder to a jit-enabled engine must loudly force the scalar
-    path — and, because both paths are bit-exact, leave the report
-    untouched."""
-
-    def test_attach_disables_jit_with_warning(self):
-        eng = TentEngine(FabricSpec(n_nodes=2),
-                         config=EngineConfig(jit_core=True), seed=3)
-        assert eng._jit is not None
-        with pytest.warns(RuntimeWarning,
-                          match="record sites cannot run under jit"):
-            eng.attach_recorder(FlightRecorder())
-        assert eng._jit is None
-
-    def test_recorded_jit_run_matches_unrecorded_scalar_run(self):
-        """recorder + jit_core => scalar path, report byte-identical to the
-        plain jit-off run (tracing stays passive even when it evicts the
-        jitted core)."""
-        import dataclasses
-        import warnings
-
-        from repro.scenarios import ScenarioRunner, get
-
-        spec = get("single_rail_flap")
-        jit_spec = dataclasses.replace(
-            spec, engine=dataclasses.replace(spec.engine, jit_core=True))
-        with pytest.warns(RuntimeWarning,
-                          match="record sites cannot run under jit"):
-            rep_on = ScenarioRunner(jit_spec).run_policy(
-                "tent", recorder=FlightRecorder())
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")  # scalar run must stay silent
-            rep_off = ScenarioRunner(spec).run_policy("tent")
-        assert rep_on.to_dict() == rep_off.to_dict()

@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis", reason="optional dev dep (requirements-dev.txt)")
-from hypothesis import assume, given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     Candidate,
@@ -321,27 +321,6 @@ def _paired_stores(n_links, queues, beta0s, beta1s):
     return out
 
 
-def _assume_normal_run(n_links, queues, beta0s, beta1s, items):
-    """Reject a draw on which the EWMA update nears the subnormal range.
-    XLA's CPU backend flushes subnormal results to zero where numpy keeps
-    them. Drawing from [0, hi] with subnormals, hypothesis falsifies the
-    scan twin with `batch=[(0, 0, 0, 2.2250738585e-313)]`; and from beta0 =
-    2.2250738585072014e-308 (the smallest normal float), length 4096 and
-    t_obs = 0.0, one update leaves beta0 at 2.113820165581841e-308 in numpy
-    and at 0.0 in the jitted twin. Within
-    one mantissa (52 binades) above that range a flushed partial product can
-    still change a rounding, so the scalar loop is replayed and the draw is
-    rejected if any state value lands there."""
-    probe, _ = _paired_stores(n_links, queues, beta0s, beta1s)
-    lo = np.finfo(float).tiny / np.finfo(float).eps
-    for step in [None] + list(items):
-        if step is not None:
-            probe._views[step[0]].on_complete(*step[1:])
-        for name in ("beta0_arr", "beta1_arr", "ewma_service_arr"):
-            v = np.abs(getattr(probe, name)[:probe.n])
-            assume(not ((v > 0) & (v < lo)).any())
-
-
 _COMPLETE_ARRS = ("beta0_arr", "beta1_arr", "queued_arr", "ewma_service_arr",
                   "completions_arr", "slow_arr", "failures_arr")
 
@@ -380,53 +359,6 @@ class TestCompleteManyParity:
             a = getattr(scalar, name)[:scalar.n]
             b = getattr(batched, name)[:batched.n]
             assert (a == b).all(), f"{name}: {a} != {b}"
-
-    @given(
-        n_links=st.integers(1, 5),
-        queues=st.lists(st.integers(0, 1 << 28), min_size=1, max_size=5),
-        beta0s=st.lists(st.floats(0.0, 1e-2, allow_subnormal=False), min_size=1, max_size=5),
-        beta1s=st.lists(st.floats(0.05, 50.0), min_size=1, max_size=5),
-        batch=st.lists(
-            st.tuples(st.integers(0, 4), st.integers(0, 1 << 22),
-                      st.integers(0, 1 << 24), st.floats(0.0, 10.0, allow_subnormal=False)),
-            min_size=1, max_size=16),
-    )
-    @settings(max_examples=40, deadline=None)
-    def test_jnp_scan_twin_matches_numpy(
-            self, n_links, queues, beta0s, beta1s, batch):
-        """`tent_on_complete_many_jnp` under x64 replays the same update."""
-        from repro.core.jit_core import x64
-
-        from repro.core.scheduler import tent_on_complete_many_jnp
-
-        items = [(slot % n_links, L, qas, tob) for slot, L, qas, tob in batch]
-        _assume_normal_run(n_links, queues, beta0s, beta1s, items)
-        ref, _ = _paired_stores(n_links, queues, beta0s, beta1s)
-        n = ref.n
-        state = {name: getattr(ref, name)[:n].copy()
-                 for name in ("beta0_arr", "beta1_arr", "queued_arr",
-                              "ewma_service_arr", "completions_arr",
-                              "ewma_alpha_arr", "beta0_alpha_arr",
-                              "bandwidth_arr")}
-        for slot, L, qas, tob in items:
-            ref._views[slot].on_complete(L, qas, tob)
-        with x64():
-            b0, b1, q, ew, comp = tent_on_complete_many_jnp(
-                state["beta0_arr"], state["beta1_arr"],
-                state["queued_arr"], state["ewma_service_arr"],
-                state["completions_arr"], state["ewma_alpha_arr"],
-                state["beta0_alpha_arr"], state["bandwidth_arr"],
-                np.asarray([i[0] for i in items]),
-                np.asarray([i[1] for i in items]),
-                np.asarray([i[2] for i in items]),
-                np.asarray([i[3] for i in items], dtype=np.float64))
-            b0, b1, q = np.asarray(b0), np.asarray(b1), np.asarray(q)
-            ew, comp = np.asarray(ew), np.asarray(comp)
-        assert (b0 == ref.beta0_arr[:n]).all()
-        assert (b1 == ref.beta1_arr[:n]).all()
-        assert (q == ref.queued_arr[:n]).all()
-        assert (ew == ref.ewma_service_arr[:n]).all()
-        assert (comp == ref.completions_arr[:n]).all()
 
 
 class TestEwmaBounded:
@@ -476,103 +408,9 @@ class TestEndToEndIntegrity:
         assert moved >= length
 
 
-def _store_pair(n_links, queues, beta0s, beta1s):
-    a, b = _paired_stores(n_links, queues, beta0s, beta1s)
-    return a, b
-
-
 class TestJitCoreKernelParity:
-    """The fixed-shape kernels behind `repro.core.jit_core` vs their scalar
-    references, over hypothesis-randomized batches: shape-bucket padding
-    (inf-penalty candidate rows, invalid slice rows, the scratch drain
-    slot) must be behaviorally invisible and every output bit-equal."""
-
-    @given(
-        queues=st.lists(st.integers(0, 1 << 28), min_size=1, max_size=9),
-        pens=st.lists(st.sampled_from([1.0, 1.5, 3.0, np.inf]),
-                      min_size=9, max_size=9),
-        excluded=st.lists(st.booleans(), min_size=9, max_size=9),
-        lengths=st.lists(st.integers(1, 1 << 20), min_size=1, max_size=20),
-        rr=st.integers(0, 500),
-        gamma=st.sampled_from([0.0, 0.05, 0.2]),
-    )
-    @settings(max_examples=60, deadline=None)
-    def test_padded_choose_matches_scalar(
-            self, queues, pens, excluded, lengths, rr, gamma):
-        """`tent_choose_wave_padded_jnp` on bucketed shapes vs the scalar
-        `tent_choose_wave` — choices, line-11 charges, queue write-back and
-        round-robin cursor, including all-excluded fallback draws."""
-        from repro.core.jit_core import x64
-
-        from repro.core.jit_core import _bucket
-        from repro.core.scheduler import tent_choose_wave_padded_jnp
-
-        n_c, n_s = len(queues), len(lengths)
-        q = np.asarray(queues, dtype=np.float64)
-        gl = gr = np.zeros(n_c)
-        bw = np.full(n_c, 25e9)
-        b0, b1 = np.zeros(n_c), np.ones(n_c)
-        pen = np.asarray(pens[:n_c], dtype=np.float64)
-        ex = np.asarray(excluded[:n_c], dtype=bool)
-        ln = np.asarray(lengths, dtype=np.float64)
-        ref = tent_choose_wave(q, gl, gr, bw, b0, b1, pen, ex, ln, rr,
-                               gamma=gamma)
-        pc, ps = _bucket(n_c), _bucket(n_s)
-
-        def pad(a, n, fill, dtype=np.float64):
-            out = np.full(n, fill, dtype=dtype)
-            out[: len(a)] = a
-            return out
-
-        valid = np.zeros(ps, dtype=bool)
-        valid[:n_s] = True
-        with x64():
-            c, qa, qo, rro = tent_choose_wave_padded_jnp(
-                pad(q, pc, 0.0), pad(gl, pc, 0.0), pad(gr, pc, 0.0),
-                pad(bw, pc, 1.0), pad(b0, pc, 0.0), pad(b1, pc, 1.0),
-                pad(pen, pc, np.inf), pad(ex, pc, True, dtype=bool),
-                pad(ln, ps, 0.0), valid, rr, gamma)
-            got = (np.asarray(c)[:n_s], np.asarray(qa)[:n_s],
-                   np.asarray(qo)[:n_c], int(rro))
-        for r, g, label in zip(ref, got,
-                               ("choices", "queued_at", "queued", "rr")):
-            assert np.array_equal(np.asarray(r), np.asarray(g)), label
-
-    @given(
-        n_links=st.integers(1, 5),
-        queues=st.lists(st.integers(0, 1 << 28), min_size=1, max_size=5),
-        beta0s=st.lists(st.floats(0.0, 1e-2, allow_subnormal=False), min_size=1, max_size=5),
-        beta1s=st.lists(st.floats(0.05, 50.0), min_size=1, max_size=5),
-        batch=st.lists(
-            st.tuples(st.integers(0, 4), st.integers(0, 1 << 22),
-                      st.integers(0, 1 << 24), st.floats(0.0, 10.0, allow_subnormal=False)),
-            min_size=1, max_size=24),
-    )
-    @settings(max_examples=40, deadline=None)
-    def test_padded_drain_adapter_matches_store(
-            self, n_links, queues, beta0s, beta1s, batch):
-        """`EngineJitCore.on_complete_many` (gather -> padded jitted scan
-        with the scratch-row batch padding -> scatter) vs the numpy store
-        drain, heavy slot repetition included."""
-        from repro.core.jit_core import EngineJitCore
-
-        class _Policy:  # the drain path only touches the store
-            _rr = 0
-            gamma = 0.05
-
-        _assume_normal_run(n_links, queues, beta0s, beta1s,
-                           [(i[0] % n_links,) + tuple(i[1:]) for i in batch])
-        a, b = _store_pair(n_links, queues, beta0s, beta1s)
-        slots = np.asarray([i[0] % n_links for i in batch], dtype=np.int64)
-        lengths = np.asarray([i[1] for i in batch], dtype=np.int64)
-        qas = np.asarray([i[2] for i in batch], dtype=np.int64)
-        tob = np.asarray([i[3] for i in batch], dtype=np.float64)
-        a.on_complete_many(slots, lengths, qas, tob)
-        EngineJitCore(_Policy(), b).on_complete_many(slots, lengths, qas, tob)
-        for name in ("beta0_arr", "beta1_arr", "queued_arr",
-                     "ewma_service_arr", "completions_arr"):
-            x, y = getattr(a, name)[:a.n], getattr(b, name)[:b.n]
-            assert (x == y).all(), f"{name}: {x} != {y}"
+    """The fused Monte Carlo spray kernel in `repro.core.jit_core` vs its
+    sequential numpy twin, over hypothesis-randomized seeds."""
 
     @given(
         seed_index=st.integers(0, 2 ** 16),
@@ -601,71 +439,42 @@ class TestJitCoreKernelParity:
         assert tuple(ref) == tuple(got)
 
 
-class TestCalendarQueueOrdering:
-    """Hypothesis twin of the seeded sweep in tests/test_calendar_parity.py:
-    the bucketed timestamp wheel must pop in exact `heapq` order — the
-    bit-parity contract the calendar-queue fabric event loop rests on."""
-
-    @given(
-        times=st.lists(
-            st.floats(0.0, 1e6, allow_nan=False, allow_infinity=False),
-            min_size=1, max_size=200),
-        width=st.sampled_from([1e-6, 1e-3, 1.0]),
-        threshold=st.sampled_from([4, 64, 4096]),
-        tie_every=st.integers(1, 8),
-    )
-    @settings(max_examples=150, deadline=None)
-    def test_pop_order_matches_heapq(self, times, width, threshold, tie_every):
-        import heapq
-
-        from repro.core import CalendarQueue
-
-        # force timestamp collisions: every tie_every-th entry reuses the
-        # previous time, exercising the in-bucket (time, seq) tie break
-        entries = []
-        for i, t in enumerate(times):
-            if i % tie_every == 0 and entries:
-                t = entries[-1][0]
-            entries.append((t, i, f"e{i}"))
-        cal = CalendarQueue(width, resize_threshold=threshold)
-        heap = []
-        for e in entries:
-            cal.push(e)
-            heapq.heappush(heap, e)
-        got = [cal.pop() for _ in range(len(entries))]
-        want = [heapq.heappop(heap) for _ in range(len(entries))]
-        assert got == want
-        assert len(cal) == 0
+class TestFabricEventOrder:
+    """Hypothesis twin of tests/test_fabric_order.py: the fabric's heap
+    loop fires `call_at` callbacks in (time, post order), with times behind
+    the clock clamped to `now`, across interleaved `run_until` drains."""
 
     @given(
         rounds=st.lists(
-            st.tuples(st.lists(st.floats(0.0, 0.05, allow_nan=False),
-                               min_size=0, max_size=8),
-                      st.integers(0, 8)),
-            min_size=1, max_size=30),
+            st.tuples(
+                st.lists(st.floats(-0.01, 0.05, allow_nan=False),
+                         min_size=0, max_size=8),
+                st.floats(0.0, 0.05, allow_nan=False)),
+            min_size=1, max_size=20),
+        tie_every=st.integers(1, 8),
     )
     @settings(max_examples=100, deadline=None)
-    def test_interleaved_monotonic_matches_heapq(self, rounds):
-        """The fabric's access pattern: pushes land at-or-after the last
-        popped time (the clock is monotonic), interleaved with drains."""
-        import heapq
+    def test_fires_in_time_then_post_order(self, rounds, tie_every):
+        from repro.core import Fabric, Topology
 
-        from repro.core import CalendarQueue
-
-        cal = CalendarQueue(1e-3)
-        heap = []
-        now, seq = 0.0, 0
-        for deltas, pops in rounds:
-            for d in deltas:
-                e = (now + d, seq, seq)
+        fab = Fabric(Topology(FabricSpec(n_nodes=2)), seed=0)
+        fired, pending = [], []
+        seq, last = 0, 0.0
+        for offsets, advance in rounds:
+            for d in offsets:
+                # every tie_every-th post reuses the previous timestamp
+                t = last if seq % tie_every == 0 and seq else fab.now + d
+                last = t
+                pending.append((max(t, fab.now), seq))
+                fab.call_at(t, lambda k=seq: fired.append(k))
                 seq += 1
-                cal.push(e)
-                heapq.heappush(heap, e)
-            for _ in range(pops):
-                if not heap:
-                    break
-                want = heapq.heappop(heap)
-                assert cal.pop() == want
-                now = want[0]
-        while heap:
-            assert cal.pop() == heapq.heappop(heap)
+            horizon = fab.now + advance
+            due = sorted(e for e in pending if e[0] <= horizon)
+            pending = [e for e in pending if e[0] > horizon]
+            before = len(fired)
+            fab.run_until(horizon)
+            assert fired[before:] == [k for _, k in due]
+        before = len(fired)
+        fab.run_until_idle()
+        assert fired[before:] == [k for _, k in sorted(pending)]
+        assert fab.idle

@@ -22,7 +22,8 @@ from repro.core import (
     Topology,
 )
 from repro.core.engine import WAVE_MIN
-from repro.scenarios import ScenarioRunner, get
+from repro.scenarios import SCENARIOS, ScenarioRunner, get
+from scenario_sizes import sized
 
 
 # ---------------------------------------------------------------------------
@@ -198,14 +199,15 @@ def _policies(spec) -> dict:
 
 
 class TestWaveScalarBitIdentity:
-    @pytest.mark.parametrize("name", ["single_rail_flap", "multi_engine_kv_incast"])
+    @pytest.mark.parametrize("name", sorted(SCENARIOS))
     def test_reports_identical(self, name):
-        """Same spec, wave on vs the pre-wave loop: every metric — byte
-        counts, makespans, latency percentiles, retries, per-rail byte maps
-        — must match exactly (same decisions => same fabric event
-        sequence). Covers the retry/exclusion interleave (flap) and the
-        omega-blend cluster path (kv_incast)."""
-        spec = get(name)
+        """Same spec, wave on vs the pre-wave loop, over the whole scenario
+        library: every metric — byte counts, makespans, latency
+        percentiles, retries, per-rail byte maps — must match exactly (same
+        decisions => same fabric event sequence). Covers the
+        retry/exclusion interleave (flap), the omega-blend cluster path
+        (kv_incast) and every serving and checkpoint workload."""
+        spec = sized(get(name))
         wave = _policies(spec)
         scalar = _policies(dataclasses.replace(
             spec,
